@@ -177,6 +177,9 @@ class App:
         ibc_token_filter: bool = True,
         square_size_upper_bound: int | None = None,
     ):
+        from celestia_app_tpu.compile_cache import enable_compile_cache
+
+        enable_compile_cache()  # before this node's first compile
         self.cms = CommitStore()
         self.chain_id = ""
         self.app_version = LATEST_VERSION

@@ -469,6 +469,8 @@ def jit_forest_sharded(k: int, mesh, axis: str):
     to the read side the way parallel/sharded_eds.py applies it to the
     write side.
     """
+    from jax.sharding import PartitionSpec as P
+
     from celestia_app_tpu.parallel.mesh import padded_rows, row_sharding
     from celestia_app_tpu.trace.journal import note_jit_build
 
@@ -476,15 +478,28 @@ def jit_forest_sharded(k: int, mesh, axis: str):
     base = forest_fn(k)
     n = 2 * k
     rows = n * (2 * n - 1)  # sum of n*w over widths n, n/2, ..., 1
-    pad = padded_rows(rows, shards) - rows
+    per = padded_rows(rows, shards) // shards
+    pad = per * shards - rows
 
-    def run(eds: jnp.ndarray):
+    def local(eds: jnp.ndarray):
+        # Every device hashes the whole (replicated) square and keeps its
+        # own row block: GSPMD cannot partition the Pallas SHA kernel the
+        # leaf batch selects on the chip, so the forest is built in a
+        # per-device body and lands already in the committed layout.
         row_flat, col_flat = base(eds)
         if pad:
             row_flat = jnp.pad(row_flat, ((0, pad), (0, 0)))
             col_flat = jnp.pad(col_flat, ((0, pad), (0, 0)))
-        return row_flat, col_flat
+        start = jax.lax.axis_index(axis) * per
+        return (
+            jax.lax.dynamic_slice_in_dim(row_flat, start, per),
+            jax.lax.dynamic_slice_in_dim(col_flat, start, per),
+        )
 
+    run = jax.shard_map(
+        local, mesh=mesh, in_specs=P(), out_specs=(P(axis, None),) * 2,
+        check_vma=False,
+    )
     out_sh = row_sharding(mesh, axis)
     note_jit_build("forest_sharded")
     from celestia_app_tpu.trace.device_ledger import track

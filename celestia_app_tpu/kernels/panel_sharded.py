@@ -216,9 +216,10 @@ def _parity_ns(shape) -> jnp.ndarray:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    from celestia_app_tpu.parallel._compat import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    # Replication check off: replicated outputs come from collectives the
+    # static checker cannot always prove replicated.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --- the sharded programs ----------------------------------------------------
@@ -490,7 +491,7 @@ def _jit_roots_sharded(k: int, shards: int, heights: tuple):
     perm = _natural_perm(k, shards, heights)
     n_steps = len(heights)
 
-    def run(*args):
+    def replicated(*args):
         ns_steps = args[:n_steps]
         hash_steps = args[n_steps:2 * n_steps]
         bot_hashes = args[2 * n_steps]
@@ -508,6 +509,14 @@ def _jit_roots_sharded(k: int, shards: int, heights: tuple):
         )
         return row_roots, col_roots, droot
 
+    # The tree hashes select the Pallas SHA kernel at these batch sizes,
+    # and GSPMD cannot partition a Mosaic kernel: run the reduction as a
+    # per-device body over the all-gathered digests (replicated in,
+    # replicated out — the same bytes on every device).
+    run = _shard_map(
+        replicated, mesh, in_specs=(P(),) * (2 * n_steps + 1),
+        out_specs=(P(), P(), P()),
+    )
     sh = row_sharding3(mesh, EXTEND_AXIS)
     rep = NamedSharding(mesh, P())
     return _track(

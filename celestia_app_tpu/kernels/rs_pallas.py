@@ -34,16 +34,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _OT = 128  # output bit-rows per grid step: one MXU row tile
 _TC = 256  # symbol-columns per grid step (lane axis)
-
-try:  # pallas imports fail on backends without Mosaic; callers gate on TPU
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover — chaos-ok: jax always ships pallas today
-    pl = None
-    pltpu = None
 
 
 def _kernel(n: int, m: int, bps: int, tc: int):
@@ -134,4 +129,4 @@ def encode_axis_pallas(
 @lru_cache(maxsize=None)
 def pallas_supported(k: int, m: int) -> bool:
     """MXU tiling wants both matmul dims in 128-multiples."""
-    return pl is not None and (k * m) % 128 == 0
+    return (k * m) % 128 == 0

@@ -48,6 +48,7 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from celestia_app_tpu.constants import NAMESPACE_SIZE, PARITY_NAMESPACE_BYTES
 
@@ -55,18 +56,10 @@ _TC = 256  # symbol-columns per grid step (lane axis), standalone kernel
 _OT_MAX = 128  # output bit-rows per grid step, standalone kernel
 _EPI_OT_MAX = 1024  # output bit-rows per grid step, epilogue kernel
 
-try:  # pallas imports fail on backends without Mosaic; interpret covers CPU
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover — chaos-ok: jax always ships pallas today
-    pl = None
-
-
 def _default_interpret() -> bool:
-    """Compiled Mosaic on the chip, interpret everywhere else."""
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # chaos-ok: no backend — interpret is the safe floor
-        return True
+    """Interpret mode on the CPU only; everywhere else the kernel compiles
+    (a backend error surfaces instead of turning into a slow path)."""
+    return jax.devices()[0].platform == "cpu"
 
 
 def pack_generator_words(G_bits: np.ndarray) -> np.ndarray:
@@ -130,9 +123,26 @@ def _pack_bit_rows(bits: jnp.ndarray) -> jnp.ndarray:
     """(R, C) 0/1 uint32 bit rows -> (R/8, C) uint8, LSB-first within a
     byte — the encode_axis repack order."""
     r, c = bits.shape
-    pb = bits.reshape(r // 8, 8, c)
-    weights = (jnp.uint32(1) << jnp.arange(8, dtype=jnp.uint32))[None, :, None]
+    # Mosaic reduces no unsigned integers: fold the same bits as int32.
+    pb = jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(r // 8, 8, c)
+    weights = (1 << jnp.arange(8, dtype=jnp.int32))[None, :, None]
     return (pb * weights).sum(axis=1).astype(jnp.uint8)
+
+
+def _byte_planes(bits: jnp.ndarray, tn: int, m: int) -> tuple:
+    """(tn*m, C) 0/1 uint32 bit rows (row p*m + 8*b + t: share p, byte
+    b, bit t) -> m/8 byte planes of (C, tn) int32, plane b holding byte
+    b of every symbol with the shares on lanes — the layout
+    kernels/sha256._leaf_tile_compute assembles message words from.
+    Split by 8 along sublanes, then a tile-local transpose: no byte-axis
+    reshape, which Mosaic refuses."""
+    c = bits.shape[1]
+    grouped = jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(tn, m, c)
+    weights = (1 << jnp.arange(8, dtype=jnp.int32))[None, :, None]
+    return tuple(
+        (grouped[:, 8 * b:8 * b + 8, :] * weights).sum(axis=1).T
+        for b in range(m // 8)
+    )
 
 
 def _xor_kernel(nw: int, ot: int, tc: int):
@@ -218,7 +228,7 @@ def xor_supported(k: int, m: int) -> bool:
     in gf/ qualifies); the padding inside the packers removes every other
     alignment constraint, so unlike the dense Pallas kernel this one has
     no MXU-tile floor."""
-    return pl is not None and m % 8 == 0
+    return m % 8 == 0
 
 
 # --------------------------------------------------------------------------
@@ -227,13 +237,13 @@ def xor_supported(k: int, m: int) -> bool:
 # --------------------------------------------------------------------------
 
 
-def _epi_kernel(nw: int, ot: int, nsym: int, bps: int, m: int):
+def _epi_kernel(nw: int, ot: int, nsym: int, m: int):
     """One batch-column's worth of bottom shares AND their leaf digests.
 
     b_ref (NW, nsym) + g_ref (NW, OT) uint32 ->
       shares_ref (OT/8, nsym) uint8   (the packed byte planes, the same
                                        layout the standalone kernel emits)
-      dig_ref    (8, OT/m)    uint32  (one digest column per share)
+      dig_ref    (8, OT/m)    uint32  (one digest lane per share)
 
     Every bottom-half leaf carries the constant parity namespace, so its
     message is 0x00 || 0xFF^29 || share — nothing but the extend output,
@@ -245,7 +255,6 @@ def _epi_kernel(nw: int, ot: int, nsym: int, bps: int, m: int):
     from celestia_app_tpu.kernels.sha256 import _leaf_tile_compute
 
     tn = ot // m
-    s = nsym * bps
     parity = [int(v) for v in PARITY_NAMESPACE_BYTES]
 
     def kernel(b_ref, g_ref, shares_ref, dig_ref):
@@ -255,16 +264,11 @@ def _epi_kernel(nw: int, ot: int, nsym: int, bps: int, m: int):
         acc = jax.lax.fori_loop(
             0, nw, step, jnp.zeros((ot, nsym), dtype=jnp.uint32)
         )
-        by = _pack_bit_rows(_fold_parity(acc))  # (tn*bps, nsym)
-        shares_ref[...] = by
-        # Byte (sym, b) of share p sits at by[p*bps + b, sym]: regroup to
-        # the (share, 512-byte) rows the leaf rounds consume — a tile-
-        # local transpose, never an HBM round trip.
-        share_tile = by.reshape(tn, bps, nsym).transpose(0, 2, 1).reshape(tn, s)
-        ns_tile = jnp.concatenate(
-            [jnp.full((tn, 1), v, dtype=jnp.uint8) for v in parity], axis=1
-        )
-        dig_ref[...] = _leaf_tile_compute(ns_tile, share_tile, tn)
+        bits = _fold_parity(acc)  # (tn*m, nsym): row p*m + 8*b + t
+        shares_ref[...] = _pack_bit_rows(bits)
+        # The same bits as byte planes, shares on lanes, straight into the
+        # leaf rounds — never an HBM round trip.
+        dig_ref[...] = _leaf_tile_compute(parity, _byte_planes(bits, tn, m), tn)
 
     return kernel
 
@@ -299,7 +303,7 @@ def extend_leaf_digests(
     planes = jnp.moveaxis(top.reshape(k, n2, nsym, bps), 3, 1)  # (k,bps,n2,nsym)
     B = pack_data_words(planes.reshape(k, bps, n2 * nsym))
     shares, dig = pl.pallas_call(
-        _epi_kernel(nw, ot, nsym, bps, m),
+        _epi_kernel(nw, ot, nsym, m),
         grid=(n2, row_tiles),  # row tiles fastest; B block constant per b
         in_specs=[
             pl.BlockSpec((nw, nsym), lambda b, r: (0, b)),
@@ -307,20 +311,22 @@ def extend_leaf_digests(
         ],
         out_specs=[
             pl.BlockSpec((ot // 8, nsym), lambda b, r: (r, b)),
-            pl.BlockSpec((8, tn), lambda b, r: (0, b * row_tiles + r)),
+            # One (8, tn) digest slab per grid step: a leading squeezed
+            # axis keeps the block's last two dims whole for any tn.
+            pl.BlockSpec((None, 8, tn), lambda b, r: (b * row_tiles + r, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Pm // 8, n2 * nsym), jnp.uint8),
-            jax.ShapeDtypeStruct((8, n2 * Pm // m), jnp.uint32),
+            jax.ShapeDtypeStruct((n2 * row_tiles, 8, tn), jnp.uint32),
         ],
         interpret=interpret,
     )(B, G_words)
     P = Pm // m  # == k for the square generator
     by = jnp.moveaxis(shares.reshape(P, bps, n2, nsym), 1, 3)
     bottom = by.reshape(P, n2, S)
-    # Digest lanes are batch-major then share (b * P + p): back to the
-    # (row, col) grid of the bottom half.
-    d = dig.reshape(8, n2, P).transpose(2, 1, 0)  # (P, n2, 8)
+    # Slab (b * row_tiles + r) holds shares r*tn .. r*tn+tn-1 of batch
+    # column b: back to the (row, col) grid of the bottom half.
+    d = dig.reshape(n2, row_tiles, 8, tn).transpose(1, 3, 0, 2)  # (rt,tn,n2,8)
     hashes = _digest_bytes(d.reshape(P * n2, 8)).reshape(P, n2, 32)
     return bottom, hashes
 
@@ -331,13 +337,7 @@ def _use_epilogue_kernel(k: int, m: int) -> bool:
     — interpret mode cannot execute the ~7k-op unrolled SHA rounds at
     square scale in reasonable time, the same reason the fused-leaf SHA
     tests jit _leaf_tile_compute directly)."""
-    if not xor_supported(k, m):
-        return False
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # chaos-ok: no backend — XLA composition floor
-        return False
-    return platform == "tpu"
+    return xor_supported(k, m) and jax.devices()[0].platform == "tpu"
 
 
 def bottom_leaf_fn(k: int, construction: str | None = None, *,

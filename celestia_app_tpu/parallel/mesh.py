@@ -108,18 +108,18 @@ def sharded_gather_fn(mesh, axis: str, rows_per_shard: int, width: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from celestia_app_tpu.parallel._compat import shard_map
     from celestia_app_tpu.trace.journal import note_jit_build
 
     def local(flat_local, idx_local):
         # flat_local: (rows_per_shard, width); idx_local: (1, batch)
         return jnp.take(flat_local, idx_local[0], axis=0)[None]
 
-    body = shard_map(
+    body = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None)),
         out_specs=P(axis, None, None),
+        check_vma=False,
     )
     fsh = row_sharding(mesh, axis)
     note_jit_build("serve_shard_gather")
@@ -196,18 +196,18 @@ def sharded_share_gather_fn(mesh, axis: str, rows_local: int, n_cols: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from celestia_app_tpu.parallel._compat import shard_map
     from celestia_app_tpu.trace.journal import note_jit_build
 
     def local(eds_local, idx_local):
         flat = eds_local.reshape(rows_local * n_cols, width)
         return jnp.take(flat, idx_local[0], axis=0)[None]
 
-    body = shard_map(
+    body = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None)),
         out_specs=P(axis, None, None),
+        check_vma=False,
     )
     note_jit_build("serve_share_gather")
     from celestia_app_tpu.trace.device_ledger import track

@@ -21,9 +21,11 @@ DAH-only callers, so no share ever re-crosses the ICI (the second share
 `all_to_all` in `make_sharded_pipeline` exists purely to hand the caller a
 row-sharded EDS).
 
-Per-device column-root blocks (2k/n x 90 bytes) stay sharded out of the
-shard_map; XLA inserts the tiny all_gather for the final DAH merkle
-(pkg/da/data_availability_header.go:92-108) wherever it is cheapest.
+The final DAH merkle (pkg/da/data_availability_header.go:92-108) runs
+INSIDE the shard_map, replicated, after one more 90-byte all_gather of
+the column roots: at k >= 1024 its 4k-leaf batch selects the Pallas SHA
+kernel, and a Mosaic kernel cannot be partitioned by GSPMD — every
+hash in these programs runs in a per-device body.
 
 All arithmetic is integer (uint8/int32 matmuls + SHA-256), so the sharded
 pipeline is bit-identical to the single-chip path on every device count -
@@ -39,8 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from celestia_app_tpu.parallel._compat import shard_map
 
 from celestia_app_tpu.constants import (
     NAMESPACE_SIZE,
@@ -62,12 +62,13 @@ def _parity_ns() -> jnp.ndarray:
 
 def _local_extend_and_roots(k: int, n: int, axis: str, _encode):
     """The shared per-device body: row-sharded ODS block in ->
-    (full_cols, row_roots, col_roots_local).
+    (full_cols, row_roots, col_roots, droot).
 
     full_cols is this device's column block of the finished EDS
     ((2k/n, 2k, S), column-major); row_roots (2k, 90) are REPLICATED —
     finished from a 90-byte subtree all_gather, never a share reshard;
-    col_roots_local (2k/n, 90) stay sharded.
+    col_roots (2k, 90) and the data root are replicated from one more
+    90-byte all_gather.
     """
 
     def local_step(ods_local: jnp.ndarray):
@@ -133,8 +134,12 @@ def _local_extend_and_roots(k: int, n: int, axis: str, _encode):
         row_roots = jnp.concatenate(
             [tm[:, 0], tx[:, 0], th[:, 0]], axis=1
         )  # (2k, 90), replicated
+        col_roots = lax.all_gather(col_roots_local, axis, tiled=True)
+        droot = merkle_root_pow2(
+            jnp.concatenate([row_roots, col_roots], axis=0)
+        )  # (32,), replicated
 
-        return full_cols, row_roots, col_roots_local
+        return full_cols, row_roots, col_roots, droot
 
     return local_step
 
@@ -161,7 +166,7 @@ def make_sharded_pipeline(
     body = _local_extend_and_roots(k, n, axis, _encode)
 
     def local_step(ods_local: jnp.ndarray):
-        full_cols, row_roots, col_roots_local = body(ods_local)
+        full_cols, row_roots, col_roots, droot = body(ods_local)
         # Hand the caller a ROW-sharded EDS: one more share all_to_all,
         # existing purely for the output layout (roots are already done).
         full_cols = lax.optimization_barrier(full_cols)
@@ -169,19 +174,15 @@ def make_sharded_pipeline(
             full_cols.transpose(1, 0, 2), axis, split_axis=0, concat_axis=1,
             tiled=True,
         )  # (2k/n, 2k, S) — this device's EDS row block.
-        return rows_blk, row_roots, col_roots_local
+        return rows_blk, row_roots, col_roots, droot
 
-    sharded = shard_map(
+    pipeline = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=P(axis, None, None),
-        out_specs=(P(axis, None, None), P(), P(axis, None)),
+        out_specs=(P(axis, None, None), P(), P(), P()),
+        check_vma=False,
     )
-
-    def pipeline(ods: jnp.ndarray):
-        eds, row_roots, col_roots = sharded(ods)
-        droot = merkle_root_pow2(jnp.concatenate([row_roots, col_roots], axis=0))
-        return eds, row_roots, col_roots, droot
 
     in_sh = NamedSharding(mesh, P(axis, None, None))
     rep = NamedSharding(mesh, P())
@@ -220,20 +221,16 @@ def make_sharded_dah_pipeline(
     body = _local_extend_and_roots(k, n, axis, _encode)
 
     def local_step(ods_local: jnp.ndarray):
-        _full_cols, row_roots, col_roots_local = body(ods_local)
-        return row_roots, col_roots_local
+        _full_cols, row_roots, col_roots, droot = body(ods_local)
+        return row_roots, col_roots, droot
 
-    sharded = shard_map(
+    pipeline = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=P(axis, None, None),
-        out_specs=(P(), P(axis, None)),
+        out_specs=(P(), P(), P()),
+        check_vma=False,
     )
-
-    def pipeline(ods: jnp.ndarray):
-        row_roots, col_roots = sharded(ods)
-        droot = merkle_root_pow2(jnp.concatenate([row_roots, col_roots], axis=0))
-        return row_roots, col_roots, droot
 
     in_sh = NamedSharding(mesh, P(axis, None, None))
     rep = NamedSharding(mesh, P())

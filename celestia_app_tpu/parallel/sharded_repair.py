@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from celestia_app_tpu.parallel._compat import shard_map
-
 from celestia_app_tpu.constants import SHARE_SIZE
 from celestia_app_tpu.da.dah import DataAvailabilityHeader
 from celestia_app_tpu.da.eds import ExtendedDataSquare
@@ -75,11 +73,12 @@ def _sharded_sweep(
         mixed = jnp.where(pm, cols, full)  # (2k, L/n, S)
         return mixed.transpose(1, 0, 2)  # line-major for the out spec
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(axis), P(), P()),
         out_specs=P(axis, None, None),
+        check_vma=False,
     )
 
     def sweep(data, present, line_idx, known_idx, R_bits):

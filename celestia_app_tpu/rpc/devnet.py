@@ -80,7 +80,7 @@ def run_validator(
     # must never sit on the block path — a first-block compile under the
     # node lock stalls every round timeout).  Small sizes cover empty/
     # near-empty devnet blocks; bigger squares hit the persistent compile
-    # cache (see spawn_devnet's JAX_COMPILATION_CACHE_DIR).
+    # cache (compile_cache.py, enabled when the node's App was built).
     from celestia_app_tpu.da.eds import warmup
 
     warmup([1, 2, 4])
@@ -153,15 +153,20 @@ def spawn_devnet(
 
     procs = []
     child_env = dict(os.environ if env is None else env)
-    # Compiles amortize across validator processes and runs; without this
-    # every child pays its own first-block jit compile under the node lock.
-    child_env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/celestia_jax_cache")
-    # Pre-warm the persistent cache ONCE before spawning: n validators
-    # compiling the same pipelines concurrently on a small host serializes
-    # onto the cores and multiplies the startup time by n; after this
-    # one-shot, every child's own warmup is a fast cache deserialization.
+    # n validators on one host is a consensus test, not a device path, and
+    # n processes cannot share one chip: pin every child to the CPU unless
+    # the caller's env names a platform itself.
+    if env is None or not env.get("JAX_PLATFORMS"):
+        child_env["JAX_PLATFORMS"] = "cpu"
+    # Pre-warm the persistent cache (compile_cache.py) ONCE before
+    # spawning: n validators compiling the same pipelines concurrently on
+    # a small host serializes onto the cores and multiplies the startup
+    # time by n; after this one-shot, every child's own warmup is a fast
+    # cache deserialization.
     subprocess.run(
         [sys.executable, "-c",
+         "from celestia_app_tpu.compile_cache import enable_compile_cache; "
+         "enable_compile_cache(); "
          "from celestia_app_tpu.da.eds import warmup; warmup([1, 2, 4])"],
         env=child_env, timeout=600,
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,

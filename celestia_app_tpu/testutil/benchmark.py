@@ -10,7 +10,7 @@ ratios, and wall times land in the trace tables for inspection.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -30,6 +30,7 @@ class ThroughputResult:
     mean_fill: float
     mean_block_bytes: float
     mean_block_seconds: float
+    block_seconds: list[float] = field(default_factory=list)
 
     @property
     def blocks_per_second(self) -> float:
@@ -61,13 +62,15 @@ def run_throughput(
     target_fill: float = 0.9,
     seed: int = 7,
     oversubmit: int = 2,
+    on_block=None,
 ) -> ThroughputResult:
     """Saturate every block with PFBs, produce, and score fill ratios.
 
     Submits `oversubmit` blobs beyond the theoretical capacity each block so
     the square builder fills to its real (alignment-padded) limit — the
     e2e saturator's behavior (txsim at full tilt); overflow txs are dropped
-    by the builder, not rejected.
+    by the builder, not rejected.  `on_block(data)`, when given, sees
+    every committed block's BlockData right after its commit.
     """
     rng = np.random.default_rng(seed)
     app = node.app
@@ -105,6 +108,8 @@ def run_throughput(
         app.finalize_block(app.last_block_time_ns + 10**9, list(data.txs))
         app.commit()
         dt = time.perf_counter() - t0
+        if on_block is not None:
+            on_block(data)
         block_bytes = sum(len(t) for t in data.txs)
         fill = block_bytes / cap_bytes
         fills.append(fill)
@@ -124,4 +129,5 @@ def run_throughput(
         mean_fill=sum(fills) / len(fills),
         mean_block_bytes=sum(sizes) / len(sizes),
         mean_block_seconds=sum(times) / len(times),
+        block_seconds=times,
     )

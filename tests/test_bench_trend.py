@@ -1,14 +1,16 @@
-"""Tier-1 seat for scripts/bench_trend.py: the checked-in BENCH_r*.json
-trajectory must parse and pass the gate (self-test mode, no device), a
-synthetic regression must be flagged, and malformed inputs must fail
-fast instead of silently dropping out of the trajectory."""
+"""Tier-1 seat for scripts/bench_trend.py: a BENCH_r*.json trajectory of
+every shape a round can leave (failed, whole, stability rerun,
+front-truncated, opt-in giant k) must parse and pass the gate (self-test
+mode, no device), a synthetic regression must be flagged, and malformed
+inputs must fail fast instead of silently dropping out of the
+trajectory.  No BENCH round is checked in: the trajectories are
+synthetic, in tmp_path."""
 
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
-import shutil
 
 import pytest
 
@@ -21,12 +23,6 @@ def _load():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-def _checked_in_rounds():
-    import glob
-
-    return sorted(glob.glob(os.path.join(REPO_ROOT, "BENCH_r*.json")))
 
 
 def _round_file(tmp_path, n, results, stability=None, errors=None,
@@ -47,10 +43,64 @@ def _round_file(tmp_path, n, results, stability=None, errors=None,
     return str(path)
 
 
-class TestCheckedInTrajectory:
-    def test_check_mode_reproduces_checked_in_rounds_and_passes(self, capsys):
+_PARTS = {"rs_fft": 1.7666, "rs_fft_md": 1.7393, "rs_dense": 1.0594,
+          "nmt_dah_jnp": 0.3621, "nmt_dah": 0.3621}
+
+
+def _summary(results, platform, **extra):
+    return {"metric": "ODS MB/s erasure-extended + DAH-hashed per chip",
+            "value": results[0]["mb_per_s"] if results else 0,
+            "unit": "MB/s", "platform": platform, "results": results,
+            "baseline_note": "synthetic", **extra}
+
+
+def _trajectory(tmp_path):
+    """Six rounds, one of each shape the driver has left: r01 failed
+    (rc=1, no data); r02 whole; r03 whole with the compute@512
+    stability rerun; r04/r05 front-truncated tails (parts salvaged);
+    r06 the opt-in giant-k row.  Returns the paths, oldest first."""
+    def put(n, rc, tail):
+        path = tmp_path / f"BENCH_r{n:02d}.json"
+        path.write_text(json.dumps(
+            {"n": n, "cmd": "bench", "rc": rc, "tail": tail, "parsed": None}
+        ))
+        return str(path)
+
+    def row(mode, k, rate, **extra):
+        return {"mode": mode, "k": k, "mb_per_s": rate,
+                "seconds_per_block": round(k * k * 512 / 1e6 / rate, 4),
+                **extra}
+
+    paths = [put(1, 1, "RuntimeError: Unable to initialize backend\n")]
+    r02 = _summary([row("compute", 512, 360.0), row("compute", 128, 110.0),
+                    row("extend", 128, 30.0), row("host", 128, 2.2)],
+                   "tpu", parts={"k": 512, "seconds": _PARTS})
+    paths.append(put(2, 0, "noise line\n" + json.dumps(r02) + "\n"))
+    r03 = _summary([row("compute", 512, 378.7),
+                    row("compute", 512, 379.4, rerun=True),
+                    row("compute", 128, 111.9), row("extend", 128, 32.0)],
+                   "tpu", stability_pct=0.2)
+    paths.append(put(3, 0, "noise line\n" + json.dumps(r03) + "\n"))
+    for n, stab in ((4, 6.4), (5, 6.1)):
+        full = json.dumps(_summary(
+            [row("compute", 128, 16.3, loadavg=1.51)], "cpu",
+            parts={"k": 128, "seconds": _PARTS,
+                   "tuned": {"rs": "rs_dense", "sha": "jnp"},
+                   "applied": {"rs": "rs_dense", "sha": "jnp"}},
+            stability_pct=stab, errors=["stage failed"],
+        ))
+        cut = full.index('"loadavg"') - 8  # inside the results list
+        paths.append(put(n, 0, full[cut:] + "\n"))
+    r06 = _summary([row("compute", 1024, 0.307)], "cpu")
+    paths.append(put(6, 0, json.dumps(r06) + "\n"))
+    return paths
+
+
+class TestTrajectory:
+    def test_check_mode_reproduces_rounds_and_passes(self, tmp_path, capsys):
         bt = _load()
-        assert bt.main(["--check"]) == 0
+        _trajectory(tmp_path)
+        assert bt.main(["--dir", str(tmp_path), "--check"]) == 0
         out = capsys.readouterr().out
         # The r02/r03 full summaries, the r04/r05 salvaged parts, and the
         # r06 giant-k opt-in row all land in one table.
@@ -78,9 +128,9 @@ class TestCheckedInTrajectory:
         # ...but --check calls it what it is: a tooling regression.
         assert bt.main(["--dir", str(tmp_path), "--check"]) == 2
 
-    def test_rounds_salvage_what_each_tail_holds(self):
+    def test_rounds_salvage_what_each_tail_holds(self, tmp_path):
         bt = _load()
-        rounds = bt.load_series(_checked_in_rounds())
+        rounds = bt.load_series(_trajectory(tmp_path))
         by_n = {r["round"]: r for r in rounds}
         assert not by_n[1]["ok"] and not by_n[1]["modes"]  # rc=1, no data
         assert ("compute", 512) in by_n[2]["modes"]
@@ -96,10 +146,9 @@ class TestCheckedInTrajectory:
 class TestRegressionGate:
     def test_injected_synthetic_regression_is_flagged(self, tmp_path, capsys):
         bt = _load()
-        for p in _checked_in_rounds():
-            shutil.copy(p, tmp_path / os.path.basename(p))
+        _trajectory(tmp_path)
         # Next round: compute@512 collapses 379 -> 40 MB/s.
-        _round_file(tmp_path, 6, [
+        _round_file(tmp_path, 7, [
             {"mode": "compute", "k": 512, "mb_per_s": 40.0,
              "seconds_per_block": 3.0},
         ])
@@ -538,8 +587,9 @@ class TestMetricsOut:
     def test_writes_trend_tables(self, tmp_path):
         bt = _load()
         out_dir = tmp_path / "metrics"
+        _trajectory(tmp_path)
         assert bt.main([
-            "--dir", REPO_ROOT, "--metrics-out", str(out_dir), "--json",
+            "--dir", str(tmp_path), "--metrics-out", str(out_dir), "--json",
         ]) == 0
         prom = (out_dir / "bench_trend.prom").read_text()
         assert "celestia_bench_trend_mb_per_s" in prom

@@ -182,7 +182,7 @@ class TestServedTxsim:
         from celestia_app_tpu.txsim.run import BlobSequence, SendSequence, run
 
         env = dict(os.environ)
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/celestia_jax_cache")
+        env["JAX_PLATFORMS"] = "cpu"  # a devnet is a consensus test
         net = spawn_devnet(n=1, base_port=26930, block_interval_ms=200, env=env)
         try:
             remote = net.client(0)
@@ -213,7 +213,7 @@ class TestServedTxsim:
         from celestia_app_tpu.rpc.devnet import spawn_devnet
 
         env = dict(os.environ)
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/celestia_jax_cache")
+        env["JAX_PLATFORMS"] = "cpu"  # a devnet is a consensus test
         net = spawn_devnet(n=3, base_port=26940, block_interval_ms=300, env=env)
         try:
             c0 = net.client(0)

@@ -132,3 +132,19 @@ def test_epilogue_kernel_extends_and_hashes(k=2):
     bottom, hashes = extend_leaf_digests(top, G_words, m, interpret=True)
     assert np.array_equal(np.asarray(bottom), np.asarray(want_bottom))
     assert np.array_equal(np.asarray(hashes), np.asarray(want_hashes))
+
+
+def test_byte_planes_match_packed_rows():
+    """The epilogue's bit rows -> byte planes glue (shares onto lanes)
+    agrees with the packed byte rows the kernel writes out, for the
+    GF(2^16) two-plane layout the k >= 256 squares use."""
+    from celestia_app_tpu.kernels.rs_xor import _byte_planes, _pack_bit_rows
+
+    tn, m, c = 4, 16, 8
+    rng = np.random.default_rng(5)
+    bits = jnp.asarray(rng.integers(0, 2, (tn * m, c), dtype=np.uint32))
+    by = np.asarray(_pack_bit_rows(bits)).reshape(tn, m // 8, c)
+    planes = _byte_planes(bits, tn, m)
+    assert len(planes) == 2
+    for b, plane in enumerate(planes):
+        assert np.array_equal(np.asarray(plane), by[:, b, :].T)

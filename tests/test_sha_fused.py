@@ -3,11 +3,11 @@
 The fused kernel assembles each NMT leaf message (0x00 || ns || share ||
 SHA padding) in VMEM instead of materializing padded lane-major words in
 HBM. The pallas kernel body is exactly `_leaf_tile_compute` — a pure jnp
-function — so off-TPU these tests jit that function directly (interpret
-mode cannot execute the ~7k-op unrolled round structure in reasonable
-time); the pallas_call wrapper itself is TPU-gated like the sibling
-test_sha_pallas.py, and bench/tpu_measure assert digest equality on
-hardware besides.
+function over the lane-major (leaves-on-lanes) byte layout — so off-TPU
+these tests run that function directly (interpret mode cannot execute
+the ~7k-op unrolled round structure in reasonable time); the pallas_call
+wrapper itself is TPU-gated like the sibling test_sha_pallas.py, and
+tests/test_chip_compile.py compiles it for v5e.
 """
 
 import hashlib
@@ -41,10 +41,26 @@ def test_tile_compute_matches_hashlib():
     ns, shares = _cases(n)
     # eager: compiling the ~7k-op unrolled graph takes minutes on this
     # 1-core CPU; op-by-op execution is seconds
-    out = _leaf_tile_compute(ns, shares, n)
+    out = _leaf_tile_compute(ns.T, (shares.T,), n)
     got = np.asarray(_digest_bytes(out.T))
     for i in range(n):
         msg = b"\x00" + bytes(np.asarray(ns[i])) + bytes(np.asarray(shares[i]))
+        assert got[i].tobytes() == hashlib.sha256(msg).digest(), i
+
+
+def test_tile_compute_two_byte_planes_constant_namespace():
+    """The extend epilogue's call shape: a constant (parity) namespace as
+    immediates and the share as two GF(2^16) byte planes (byte b of
+    symbol s in plane b, row s)."""
+    from celestia_app_tpu.constants import PARITY_NAMESPACE_BYTES
+
+    n = 4
+    _, shares = _cases(n, seed=7)
+    planes = (shares[:, 0::2].T, shares[:, 1::2].T)
+    out = _leaf_tile_compute(list(PARITY_NAMESPACE_BYTES), planes, n)
+    got = np.asarray(_digest_bytes(out.T))
+    for i in range(n):
+        msg = b"\x00" + PARITY_NAMESPACE_BYTES + bytes(np.asarray(shares[i]))
         assert got[i].tobytes() == hashlib.sha256(msg).digest(), i
 
 
@@ -57,7 +73,7 @@ def test_tile_compute_matches_unfused_path():
     prefix = jnp.zeros((n, 1), dtype=jnp.uint8)
     msgs = jnp.concatenate([prefix, ns, shares], axis=1)
     want = np.asarray(_sha256_jnp(msgs))
-    out = _leaf_tile_compute(ns, shares, n)  # eager, see above
+    out = _leaf_tile_compute(ns.T, (shares.T,), n)  # eager, see above
     got = np.asarray(_digest_bytes(out.T))
     assert np.array_equal(got, want)
 
@@ -94,7 +110,7 @@ def test_leaf_digests_rides_fused_kernel(monkeypatch):
     _, _, want = leaf_digests(ns, data)
 
     def body_path(ns2, shares2):
-        out = _leaf_tile_compute(ns2, shares2, ns2.shape[0])
+        out = _leaf_tile_compute(ns2.T, (shares2.T,), ns2.shape[0])
         return _digest_bytes(out.T)
 
     calls = []
