@@ -1,0 +1,16 @@
+"""Least time of one extend + DAH over its measured device time, in %.
+Least time = the bytes the algorithm must move (benchmark/counts.py) over
+the HBM peak of the device kind (benchmark/peaks.json): a bytes-only bound."""
+
+
+def read(ctx):
+    if not ctx["peaks"]:
+        return None
+    from benchmark.counts import extend_dah_bytes
+    from benchmark.run import read_metric
+
+    ms = read_metric("extend_dah_device_ms", ctx)
+    if ms is None:
+        return None
+    least = extend_dah_bytes(ctx["k"]) / ctx["peaks"]["hbm_bytes_per_s"]
+    return least / (ms / 1e3) * 100.0
