@@ -333,17 +333,19 @@ class App:
     def prepare_proposal(self, raw_txs: list[bytes]) -> BlockData:
         # telemetry.MeasureSince parity (prepare_proposal.go:23); joins
         # the block's trace when the caller set one (trace/context.py).
+        # The height and side ride the baggage: every span below (ante,
+        # square build, the pipeline's children) carries them on its row.
+        height = self.height + 1
         with trace_span("prepare_proposal", layer="app",
-                        height=self.height + 1, n_txs=len(raw_txs)):
+                        height=height, n_txs=len(raw_txs),
+                        baggage={"height": height, "phase": "prepare"}):
             raw_txs = self._cap_block_bytes(raw_txs)
             filtered = self._filter_txs(raw_txs)
             sq, kept = square.build(filtered, self.max_effective_square_size())
             if sq.is_empty():
                 dah = min_data_availability_header()
                 return BlockData(tuple(kept), 1, dah.hash())
-            with trace_span("square_pipeline", layer="device", e2e="dispatch",
-                            k=sq.size, phase="prepare"):
-                root = self._square_root(sq.size, sq.share_bytes())
+            root = self._pipeline_root(sq, "prepare")
             return BlockData(tuple(kept), sq.size, root)
 
     def _cap_block_bytes(self, raw_txs: list[bytes]) -> list[bytes]:
@@ -377,29 +379,32 @@ class App:
         classified = [(raw, unmarshal_blob_tx(raw)) for raw in raw_txs]
         normal: list[bytes] = []
         blob: list[bytes] = []
-        for raw, btx in classified:
-            if btx is not None:
-                continue
-            try:
-                tx = Tx.unmarshal(raw)
-                if any(isinstance(m, MsgPayForBlobs) for m in tx.msgs()):
-                    continue  # PFB outside a BlobTx is invalid
-                run_ante(self, ctx, tx, is_check_tx=False, tx_bytes=raw)
-                normal.append(raw)
-            except (AnteError, ValueError, OutOfGas):
-                continue
+        with trace_span("ante", layer="app"):
+            for raw, btx in classified:
+                if btx is not None:
+                    continue
+                try:
+                    tx = Tx.unmarshal(raw)
+                    if any(isinstance(m, MsgPayForBlobs) for m in tx.msgs()):
+                        continue  # PFB outside a BlobTx is invalid
+                    run_ante(self, ctx, tx, is_check_tx=False, tx_bytes=raw)
+                    normal.append(raw)
+                except (AnteError, ValueError, OutOfGas):
+                    continue
         blob_entries = [(raw, btx) for raw, btx in classified if btx is not None]
         validated = validate_blob_txs_batched([b for _, b in blob_entries])
-        for (raw, btx), v in zip(blob_entries, validated):
-            if isinstance(v, BlobTxError):
-                continue
-            try:
-                run_ante(
-                    self, ctx, Tx.unmarshal(btx.tx), is_check_tx=False, tx_bytes=btx.tx
-                )
-                blob.append(raw)
-            except (AnteError, ValueError, OutOfGas):
-                continue
+        with trace_span("ante", layer="app"):
+            for (raw, btx), v in zip(blob_entries, validated):
+                if isinstance(v, BlobTxError):
+                    continue
+                try:
+                    run_ante(
+                        self, ctx, Tx.unmarshal(btx.tx), is_check_tx=False,
+                        tx_bytes=btx.tx,
+                    )
+                    blob.append(raw)
+                except (AnteError, ValueError, OutOfGas):
+                    continue
         return normal + blob
 
     def speculate_proposal(
@@ -446,8 +451,10 @@ class App:
         outcomes = registry().counter(
             "celestia_process_proposal_total", "ProcessProposal verdicts"
         )
+        height = self.height + 1
         with trace_span("process_proposal", layer="app",
-                        height=self.height + 1, n_txs=len(data.txs)):
+                        height=height, n_txs=len(data.txs),
+                        baggage={"height": height, "phase": "process"}):
             try:
                 ok = self._process_proposal(data)
             except Exception:
@@ -480,19 +487,21 @@ class App:
         validated = iter(
             validate_blob_txs_batched([b for _, b in classified if b is not None])
         )
-        for raw, btx in classified:
-            if btx is None:
-                tx = Tx.unmarshal(raw)
-                if any(isinstance(m, MsgPayForBlobs) for m in tx.msgs()):
-                    return False  # PFB must ride in a BlobTx (:77-88)
-                run_ante(self, ctx, tx, is_check_tx=False, tx_bytes=raw)
-            else:
-                v = next(validated)
-                if isinstance(v, BlobTxError):
-                    raise v
-                run_ante(
-                    self, ctx, Tx.unmarshal(btx.tx), is_check_tx=False, tx_bytes=btx.tx
-                )
+        with trace_span("ante", layer="app"):
+            for raw, btx in classified:
+                if btx is None:
+                    tx = Tx.unmarshal(raw)
+                    if any(isinstance(m, MsgPayForBlobs) for m in tx.msgs()):
+                        return False  # PFB must ride in a BlobTx (:77-88)
+                    run_ante(self, ctx, tx, is_check_tx=False, tx_bytes=raw)
+                else:
+                    v = next(validated)
+                    if isinstance(v, BlobTxError):
+                        raise v
+                    run_ante(
+                        self, ctx, Tx.unmarshal(btx.tx), is_check_tx=False,
+                        tx_bytes=btx.tx,
+                    )
 
         sq = square.construct(list(data.txs), self.max_effective_square_size())
         if sq.size != data.square_size:
@@ -502,7 +511,7 @@ class App:
         # Root equality (:152) over the square REBUILT from the raw txs
         # above — the own-root memo only skips re-running the pipeline on
         # bytes this node already extended (its own Prepare, usually).
-        return self._square_root(sq.size, sq.share_bytes()) == data.hash
+        return self._pipeline_root(sq, "process") == data.hash
 
     @staticmethod
     def _square_key(size: int, share_bytes: list[bytes]) -> tuple:
@@ -541,14 +550,34 @@ class App:
             return last[1]
         return None
 
-    def _square_root(self, size: int, share_bytes: list[bytes]) -> bytes:
-        """DAH hash of a built square, memoized on the square's content."""
-        key = self._square_key(size, share_bytes)
+    def _pipeline_root(self, sq, phase: str) -> bytes:
+        """The built square's DAH hash under the `square_pipeline` span
+        (phase=prepare on the proposer, process on a validator), each of
+        its host steps a child span: `share_pack` here and in
+        extend_shares, `square_digest`, `ods_upload`, `extend_dispatch`,
+        `roots_wait`."""
+        with trace_span("square_pipeline", layer="device",
+                        e2e="dispatch" if phase == "prepare" else None,
+                        k=sq.size, phase=phase) as span:
+            with trace_span("share_pack", layer="host"):
+                shares = sq.share_bytes()
+            return self._square_root(sq.size, shares, span)
+
+    def _square_root(self, size: int, share_bytes: list[bytes],
+                     span: dict | None = None) -> bytes:
+        """DAH hash of a built square, memoized on the square's content;
+        `span` (the open square_pipeline span) learns memo=hit|miss."""
+        with trace_span("square_digest", layer="host"):
+            key = self._square_key(size, share_bytes)
         cached = self._own_roots.get(key)
+        if span is not None:
+            span["memo"] = "miss" if cached is None else "hit"
         if cached is not None:
             return cached
         eds = extend_shares(share_bytes)
-        root = DataAvailabilityHeader.from_eds(eds).hash()
+        # The first host read of the roots: waits for the device program.
+        with trace_span("roots_wait", layer="device"):
+            root = DataAvailabilityHeader.from_eds(eds).hash()
         from celestia_app_tpu.serve import serve_heights
 
         if serve_heights() > 0:
@@ -576,34 +605,38 @@ class App:
         (harnesses without a consensus plane).  `evidence` carries
         consensus.votes.Equivocation records (ByzantineValidators)."""
         height = self.height + 1
-        block_store = self.cms.working.branch()
-        ctx = Ctx(block_store, height, time_ns, self.app_version)
+        with trace_span("finalize_block", layer="app", height=height,
+                        n_txs=len(txs), baggage={"height": height}):
+            block_store = self.cms.working.branch()
+            ctx = Ctx(block_store, height, time_ns, self.app_version)
 
-        self._begin_block(ctx, time_ns, last_commit_signers, evidence)
-        results = [self._deliver_tx(ctx, raw) for raw in txs]
-        self._end_block(ctx, height)
-        from celestia_app_tpu.trace.metrics import registry
+            self._begin_block(ctx, time_ns, last_commit_signers, evidence)
+            results = [self._deliver_tx(ctx, raw) for raw in txs]
+            self._end_block(ctx, height)
+            from celestia_app_tpu.trace.metrics import registry
 
-        delivered = registry().counter(
-            "celestia_txs_delivered_total", "delivered txs by result code"
-        )
-        for r in results:
-            delivered.inc(code=str(r.code))
+            delivered = registry().counter(
+                "celestia_txs_delivered_total", "delivered txs by result code"
+            )
+            for r in results:
+                delivered.inc(code=str(r.code))
 
-        self.cms.working.write_back(block_store)
-        self.height = height
-        self.last_block_time_ns = time_ns
-        return results
+            self.cms.working.write_back(block_store)
+            self.height = height
+            self.last_block_time_ns = time_ns
+            return results
 
     def commit(self) -> bytes:
-        app_hash = self.cms.commit(self.height)
-        self._check_state = None  # reset mempool check state each block
-        from celestia_app_tpu.trace.metrics import registry
+        with trace_span("commit", layer="app", height=self.height,
+                        baggage={"height": self.height}):
+            app_hash = self.cms.commit(self.height)
+            self._check_state = None  # reset mempool check state each block
+            from celestia_app_tpu.trace.metrics import registry
 
-        registry().gauge("celestia_block_height", "last committed height").set(
-            self.height
-        )
-        return app_hash
+            registry().gauge(
+                "celestia_block_height", "last committed height"
+            ).set(self.height)
+            return app_hash
 
     def _begin_block(
         self,

@@ -819,12 +819,9 @@ class ExtendedDataSquare:
     def compute(
         cls, ods: np.ndarray, construction: str | None = None
     ) -> "ExtendedDataSquare":
-        import time
-
-        from celestia_app_tpu.kernels.fused import pipeline_mode
-        from celestia_app_tpu.trace import journal
-
         from celestia_app_tpu.chaos.degrade import guarded_dispatch
+        from celestia_app_tpu.trace import journal
+        from celestia_app_tpu.trace.context import trace_span
 
         k = ods.shape[0]
         if k & (k - 1) or not 1 <= k <= MAX_CODEC_SQUARE_SIZE:
@@ -860,13 +857,13 @@ class ExtendedDataSquare:
             if ods.dtype != jnp.uint8:  # the host path coerces; so must this
                 ods = jnp.asarray(ods, dtype=jnp.uint8)
             state = pipeline_cache_state(k, construction)
-            t0 = time.perf_counter()
-            mode, (eds, rr, cr, droot) = guarded_dispatch(
-                lambda m: _pipeline_for_mode(m, k, construction), ods, k=k
-            )
+            with trace_span("extend_dispatch", layer="device", k=k) as disp:
+                mode, (eds, rr, cr, droot) = guarded_dispatch(
+                    lambda m: _pipeline_for_mode(m, k, construction), ods, k=k
+                )
             journal.record(
                 "compute", k, mode=mode, compile=state,
-                dispatch_ms=(time.perf_counter() - t0) * 1e3,
+                dispatch_ms=disp.get("duration_ms"),
                 **_panel_fields(mode, k),
                 **({"speculation": spec_outcome} if spec_outcome else {}),
             )
@@ -877,10 +874,10 @@ class ExtendedDataSquare:
             # A retry after a REAL mid-dispatch failure re-uploads from
             # the host copy, so donation never poisons the retry.
             state = pipeline_cache_state(k, construction, owned=True)
-            t0 = time.perf_counter()
             from celestia_app_tpu.kernels.fused import pipeline_mode_for_k
 
-            if pipeline_mode_for_k(k) in ("panel", "sharded_panel"):
+            panel = pipeline_mode_for_k(k) in ("panel", "sharded_panel")
+            with trace_span("ods_upload", layer="device", k=k) as upload:
                 # Panel mode streams panels out of the HOST copy one at a
                 # time (the sharded runner additionally lays each step
                 # out row-sharded across the mesh) — a whole-square
@@ -889,20 +886,23 @@ class ExtendedDataSquare:
                 # documented residency bound.  A mid-call ladder fall
                 # still works: the materializing jits accept the host
                 # array and upload at dispatch.
-                x = np.ascontiguousarray(ods, dtype=np.uint8)
-            else:
-                x = jnp.asarray(ods, dtype=jnp.uint8)
-            t1 = time.perf_counter()
-            mode, (eds, rr, cr, droot) = guarded_dispatch(
-                lambda m: _pipeline_for_mode(m, k, construction, owned=True),
-                x,
-                refresh=lambda: jnp.asarray(ods, dtype=jnp.uint8),
-                k=k,
-            )
+                if panel:
+                    x = np.ascontiguousarray(ods, dtype=np.uint8)
+                else:
+                    x = jnp.asarray(ods, dtype=jnp.uint8)
+            with trace_span("extend_dispatch", layer="device", k=k) as disp:
+                mode, (eds, rr, cr, droot) = guarded_dispatch(
+                    lambda m: _pipeline_for_mode(
+                        m, k, construction, owned=True
+                    ),
+                    x,
+                    refresh=lambda: jnp.asarray(ods, dtype=jnp.uint8),
+                    k=k,
+                )
             journal.record(
                 "compute", k, mode=mode, compile=state,
-                upload_ms=(t1 - t0) * 1e3,
-                dispatch_ms=(time.perf_counter() - t1) * 1e3,
+                upload_ms=upload.get("duration_ms"),
+                dispatch_ms=disp.get("duration_ms"),
                 **_panel_fields(mode, k),
                 **({"speculation": spec_outcome} if spec_outcome else {}),
             )
@@ -978,16 +978,23 @@ def extend_shares(
     in-process device pipeline — the node must keep committing, and both
     paths are bit-identical, so the fallback never forks consensus.
     """
+    from celestia_app_tpu.trace.context import trace_span
+
     n = len(shares)
     k = int(round(n ** 0.5))
     if k * k != n:
         raise ValueError(f"share count {n} is not a perfect square")
     if k & (k - 1) or k > MAX_CODEC_SQUARE_SIZE:
         raise ValueError(f"invalid square size {k}")
-    for i, s in enumerate(shares):
-        if len(s) != SHARE_SIZE:
-            raise ValueError(f"share {i} has length {len(s)}, want {SHARE_SIZE}")
-    ods = np.frombuffer(b"".join(shares), dtype=np.uint8).reshape(k, k, SHARE_SIZE)
+    with trace_span("share_pack", layer="host", k=k):
+        for i, s in enumerate(shares):
+            if len(s) != SHARE_SIZE:
+                raise ValueError(
+                    f"share {i} has length {len(s)}, want {SHARE_SIZE}"
+                )
+        ods = np.frombuffer(
+            b"".join(shares), dtype=np.uint8
+        ).reshape(k, k, SHARE_SIZE)
     if square_backend() == "bridge":
         result = _try_bridge_extend(ods)
         if result is not None:

@@ -62,7 +62,8 @@ def _memo_key(blob: Blob, threshold: int) -> tuple:
 
 
 def create_commitments_batched(
-    blobs: list[Blob], subtree_root_threshold: int = SUBTREE_ROOT_THRESHOLD
+    blobs: list[Blob], subtree_root_threshold: int = SUBTREE_ROOT_THRESHOLD,
+    stats: dict | None = None,
 ) -> list[bytes]:
     """Commitments for many blobs with all hashing batched on device.
 
@@ -70,7 +71,11 @@ def create_commitments_batched(
     scheduled as one device call per distinct chunk size. Results are
     memoized by blob content, so revalidation of an already-seen blob
     (Prepare/Process after CheckTx) costs one sha256 of its data.
+    `stats`, when given, receives `memo_hits`: the blobs answered from
+    the memo.
     """
+    if stats is not None:
+        stats["memo_hits"] = 0
     if not blobs:
         return []
 
@@ -78,6 +83,8 @@ def create_commitments_batched(
     with _COMMIT_MEMO_LOCK:
         have = {k: _COMMIT_MEMO[k] for k in keys if k in _COMMIT_MEMO}
     missing = [i for i, k in enumerate(keys) if k not in have]
+    if stats is not None:
+        stats["memo_hits"] = len(keys) - len(missing)
     if not missing:
         return [have[k] for k in keys]
     fresh = _create_commitments_uncached(

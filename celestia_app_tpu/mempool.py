@@ -402,7 +402,7 @@ class PriorityMempool:
             ctx = current_context()
         if not trace_enabled():
             # Muted-tracing fast path: no span context (new_context draws
-            # urandom per span — measurable next to a small tx's hash);
+            # fresh ids per span — measurable next to a small tx's hash);
             # the admission semantics are identical.
             return self._insert_refreshing(tx, priority, height, ctx, ns, {})
         with trace_span(
@@ -635,36 +635,38 @@ class PriorityMempool:
         `mempool_wait` e2e observation per reaped tx (insert -> reap
         residency).
         """
-        from celestia_app_tpu.trace.context import export_span, new_context
+        from celestia_app_tpu.trace.context import (
+            SpanClock,
+            export_span,
+            new_context,
+        )
         from celestia_app_tpu.trace.spans import observe_e2e
         from celestia_app_tpu.trace.tracer import trace_enabled
 
-        start_unix_ns = time.time_ns()
-        t0 = time.perf_counter_ns()
-        ordered = sorted(
-            self._snapshot(), key=lambda e: (-e.priority, e.seq)
-        )
-        resident_bytes = sum(len(e.tx) for e in ordered)
-        use_drr = (
-            self.shards > 0
-            and max_bytes is not None
-            and resident_bytes > max_bytes
-        )
-        if use_drr:
-            out, reaped_entries, skipped, total = self._drr_reap(
-                ordered, max_bytes
+        with SpanClock("mempool_reap") as clock:
+            ordered = sorted(
+                self._snapshot(), key=lambda e: (-e.priority, e.seq)
             )
-        else:
-            out, reaped_entries = [], []
-            total = skipped = 0
-            for e in ordered:
-                if max_bytes is not None and total + len(e.tx) > max_bytes:
-                    skipped += 1
-                    continue
-                out.append(e.tx)
-                reaped_entries.append(e)
-                total += len(e.tx)
-        elapsed_ns = time.perf_counter_ns() - t0
+            resident_bytes = sum(len(e.tx) for e in ordered)
+            use_drr = (
+                self.shards > 0
+                and max_bytes is not None
+                and resident_bytes > max_bytes
+            )
+            if use_drr:
+                out, reaped_entries, skipped, total = self._drr_reap(
+                    ordered, max_bytes
+                )
+            else:
+                out, reaped_entries = [], []
+                total = skipped = 0
+                for e in ordered:
+                    if max_bytes is not None and total + len(e.tx) > max_bytes:
+                        skipped += 1
+                        continue
+                    out.append(e.tx)
+                    reaped_entries.append(e)
+                    total += len(e.tx)
         if trace_enabled():
             # The span joins the trace of the first REAPED tx — the same
             # trace the block built from this reap adopts
@@ -675,7 +677,7 @@ class PriorityMempool:
             )
             ctx = first_ctx.child() if first_ctx is not None else new_context()
             export_span(
-                "mempool_reap", ctx, start_unix_ns, elapsed_ns,
+                "mempool_reap", ctx, clock,
                 {"layer": "mempool", "n_txs": len(out), "reap_bytes": total,
                  "skipped": skipped, "resident": len(ordered),
                  "drr": use_drr,

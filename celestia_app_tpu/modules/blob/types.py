@@ -131,31 +131,40 @@ def validate_blob_txs_batched(
     choose.  Equivalent to [validate_blob_tx(b) for b in btxs].
     """
     from celestia_app_tpu.inclusion.batched import create_commitments_batched
+    from celestia_app_tpu.trace.context import trace_span
 
-    results: list[MsgPayForBlobs | BlobTxError] = []
-    todo: list[tuple[int, MsgPayForBlobs]] = []
-    all_blobs = []
-    for btx in btxs:
-        try:
-            msg = _structural_checks(btx)
-        except BlobTxError as e:
-            results.append(e)
-            continue
-        todo.append((len(results), msg))
-        results.append(msg)
-        all_blobs.extend(btx.blobs)
+    with trace_span("blob_validate", layer="app") as span:
+        results: list[MsgPayForBlobs | BlobTxError] = []
+        todo: list[tuple[int, MsgPayForBlobs]] = []
+        all_blobs = []
+        for btx in btxs:
+            try:
+                msg = _structural_checks(btx)
+            except BlobTxError as e:
+                results.append(e)
+                continue
+            todo.append((len(results), msg))
+            results.append(msg)
+            all_blobs.extend(btx.blobs)
 
-    commitments = create_commitments_batched(all_blobs, subtree_root_threshold)
-    pos = 0
-    for idx, msg in todo:
-        n = len(msg.share_commitments)
-        got = commitments[pos : pos + n]
-        pos += n
-        for i, c in enumerate(got):
-            if c != msg.share_commitments[i]:
-                results[idx] = BlobTxError(f"blob {i} share commitment mismatch")
-                break
-    return results
+        # memo_hits: commitments answered from the process-wide memo
+        # (inclusion/batched._COMMIT_MEMO) without hashing.
+        commitments = create_commitments_batched(
+            all_blobs, subtree_root_threshold, stats=span
+        )
+        span["n_blobs"] = len(all_blobs)
+        pos = 0
+        for idx, msg in todo:
+            n = len(msg.share_commitments)
+            got = commitments[pos : pos + n]
+            pos += n
+            for i, c in enumerate(got):
+                if c != msg.share_commitments[i]:
+                    results[idx] = BlobTxError(
+                        f"blob {i} share commitment mismatch"
+                    )
+                    break
+        return results
 
 
 def gas_to_consume(blob_sizes: tuple[int, ...], gas_per_blob_byte: int) -> int:

@@ -408,6 +408,7 @@ class DasProvider:
             ShareWithheld,
             _verify_gate_armed,
         )
+        from celestia_app_tpu.trace.context import trace_span
 
         entry = self.entry(height)
         try:
@@ -418,19 +419,20 @@ class DasProvider:
         except BadProofDetected:
             coverage_tick(height, entry.k, [(row, col)], "tampered")
             raise
-        coverage_tick(
-            height, entry.k, [(row, col)],
-            "verified" if _verify_gate_armed(entry) else "sampled",
-        )
-        return {
-            "height": height,
-            "row": row,
-            "col": col,
-            "axis": axis,
-            "square_size": entry.k,
-            "proof": to_jsonable(proof),
-            "data_root": entry.data_root.hex(),
-        }
+        with trace_span("proof_encode", root=False, layer="serve"):
+            coverage_tick(
+                height, entry.k, [(row, col)],
+                "verified" if _verify_gate_armed(entry) else "sampled",
+            )
+            return {
+                "height": height,
+                "row": row,
+                "col": col,
+                "axis": axis,
+                "square_size": entry.k,
+                "proof": to_jsonable(proof),
+                "data_root": entry.data_root.hex(),
+            }
 
     def shares_payload(self, height: int, namespace_hex: str) -> dict:
         from celestia_app_tpu.proof.share_proof import ods_namespace_range

@@ -27,8 +27,11 @@ costs latency, never a wrong or missing proof.
 Queueing: concurrent `share_proof` callers park on a shared queue; the
 first arrival becomes the batch leader, waits $CELESTIA_SERVE_BATCH_MS
 (default 0: drain whatever queued), and answers everyone in one
-dispatch.  Latency lands on celestia_proof_latency_seconds{phase}:
-queue_wait and total per sample, gather and assemble per batch.
+dispatch.  Latency lands on celestia_proof_latency_seconds{phase="total"}
+per sample; each batch group's `proof_gather` and `proof_assemble` spans
+(trace/context.trace_span: rows, `celestia_proof_<step>_seconds`, and a
+profiler annotation each) time its steps, and the group's `proof_serve`
+row sums its samples' queue wait (`queue_wait_ms`).
 
 Adversary detection (chaos/adversary.py — the ISSUE-10 attack model):
 
@@ -115,9 +118,8 @@ def _latency():
 
     return registry().histogram(
         "celestia_proof_latency_seconds",
-        "DAS proof serving latency by phase (queue_wait/gather/assemble "
-        "per the sampler; total is per served sample, labeled with the "
-        "served share's capped namespace)",
+        "DAS proof serving latency (phase=total, per served sample, "
+        "labeled with the served share's capped namespace)",
         buckets=DEVICE_SECONDS_BUCKETS,
     )
 
@@ -288,8 +290,6 @@ class ProofSampler:
     def _serve_batch(self, batch: list[_Pending]) -> None:
         lat = _latency()
         t0 = time.perf_counter()
-        for p in batch:
-            lat.observe(t0 - p.t_submit, phase="queue_wait")
         by_entry: dict[tuple, list[_Pending]] = {}
         for p in batch:
             by_entry.setdefault((id(p.entry), p.axis), []).append(p)
@@ -305,6 +305,7 @@ class ProofSampler:
             entry = group[0].entry
             tracer.write(
                 "proof_serve", batch=len(group), heights=len(by_entry),
+                queue_wait_ms=sum(t0 - p.t_submit for p in group) * 1e3,
                 height=getattr(entry, "height", None),
                 mode=serve_mode(),
                 shards=getattr(entry, "shards", 0),
@@ -329,8 +330,8 @@ class ProofSampler:
                 for p in group:
                     # Per-sample total carries the served share's capped
                     # namespace — the read path's per-tenant latency view
-                    # (batch-level gather/assemble stay unlabeled: one
-                    # dispatch serves many tenants).
+                    # (the group's gather/assemble spans stay unlabeled:
+                    # one dispatch serves many tenants).
                     lat.observe(
                         time.perf_counter() - p.t_submit, phase="total",
                         namespace=_proof_namespace_label(p.proof),
@@ -404,63 +405,67 @@ class ProofSampler:
         return proofs
 
     def _batched(self, entry, coords, axis: str = "row") -> list[ShareProof]:
-        lat = _latency()
-        n = 2 * entry.k
-        # Row sampling proves leaf `col` of tree `row`; column sampling
-        # the transpose — leaf `row` of column tree `col`, whose root is
-        # data-root leaf 2k + col.
-        if axis == "col":
-            plans = [_sample_coords(n, row) for row, _ in coords]
-            trees = [col for _, col in coords]
-        else:
-            plans = [_sample_coords(n, col) for _, col in coords]
-            trees = [row for row, _ in coords]
-        node_idx: list[int] = []
-        for tree, plan in zip(trees, plans):
-            node_idx.extend(
-                entry.flat_index(tree, lvl, i) for lvl, i in plan
-            )
-        t0 = time.perf_counter()
-        nodes = entry.gather(axis, node_idx)
-        shares = entry.gather_shares(coords)
-        lat.observe(time.perf_counter() - t0, phase="gather")
+        from celestia_app_tpu.trace.context import trace_span
 
-        t1 = time.perf_counter()
+        n = 2 * entry.k
+        tier = "device" if getattr(entry, "device_resident", True) else "host"
+        with trace_span("proof_gather", root=False, layer="serve",
+                        batch=len(coords), tier=tier):
+            # Row sampling proves leaf `col` of tree `row`; column
+            # sampling the transpose — leaf `row` of column tree `col`,
+            # whose root is data-root leaf 2k + col.
+            if axis == "col":
+                plans = [_sample_coords(n, row) for row, _ in coords]
+                trees = [col for _, col in coords]
+            else:
+                plans = [_sample_coords(n, col) for _, col in coords]
+                trees = [row for row, _ in coords]
+            node_idx: list[int] = []
+            for tree, plan in zip(trees, plans):
+                node_idx.extend(
+                    entry.flat_index(tree, lvl, i) for lvl, i in plan
+                )
+            nodes = entry.gather(axis, node_idx)
+            shares = entry.gather_shares(coords)
+
         from celestia_app_tpu import merkle
 
-        all_roots = entry.row_roots + entry.col_roots
-        out: list[ShareProof] = []
-        pos = 0
-        for (row, col), plan, share_row in zip(coords, plans, shares):
-            share = bytes(share_row.tobytes())
-            nmt_nodes = tuple(
-                bytes(nodes[pos + i].tobytes()) for i in range(len(plan))
-            )
-            pos += len(plan)
-            ns = (
-                share[:NAMESPACE_SIZE]
-                if row < entry.k and col < entry.k
-                else PARITY_NAMESPACE_BYTES
-            )
-            if axis == "col":
-                leaf, root_index = row, n + col
-            else:
-                leaf, root_index = col, row
-            out.append(ShareProof(
-                data=(share,),
-                share_proofs=(NmtRangeProof(leaf, leaf + 1, nmt_nodes, n),),
-                namespace=ns,
-                row_proof=RowProof(
-                    row_roots=(all_roots[root_index],),
-                    proofs=(tuple(
-                        merkle.path_from_levels(entry.root_levels, root_index)
-                    ),),
-                    start_row=root_index,
-                    end_row=root_index + 1,
-                    total=2 * n,
-                ),
-            ))
-        lat.observe(time.perf_counter() - t1, phase="assemble")
+        with trace_span("proof_assemble", root=False, layer="serve",
+                        batch=len(coords)):
+            all_roots = entry.row_roots + entry.col_roots
+            out: list[ShareProof] = []
+            pos = 0
+            for (row, col), plan, share_row in zip(coords, plans, shares):
+                share = bytes(share_row.tobytes())
+                nmt_nodes = tuple(
+                    bytes(nodes[pos + i].tobytes()) for i in range(len(plan))
+                )
+                pos += len(plan)
+                ns = (
+                    share[:NAMESPACE_SIZE]
+                    if row < entry.k and col < entry.k
+                    else PARITY_NAMESPACE_BYTES
+                )
+                if axis == "col":
+                    leaf, root_index = row, n + col
+                else:
+                    leaf, root_index = col, row
+                out.append(ShareProof(
+                    data=(share,),
+                    share_proofs=(NmtRangeProof(leaf, leaf + 1, nmt_nodes, n),),
+                    namespace=ns,
+                    row_proof=RowProof(
+                        row_roots=(all_roots[root_index],),
+                        proofs=(tuple(
+                            merkle.path_from_levels(
+                                entry.root_levels, root_index
+                            )
+                        ),),
+                        start_row=root_index,
+                        end_row=root_index + 1,
+                        total=2 * n,
+                    ),
+                ))
         return out
 
     def _host_batch(self, entry, coords, axis: str = "row") -> list[ShareProof]:

@@ -10,7 +10,9 @@ ledger, in two halves:
 
 PROGRAM LEDGER — every jit-cache family in `da/`, `kernels/`, `serve/`,
 `parallel/` wraps its freshly built program with `track(fn, family,
-**key)` (enforced by trace_lint rule 8).  Per program key (family, k,
+**key)` (enforced by trace_lint rule 8), which also names the program
+after its family on the device trace (`jit_<family>`, never the builder
+closure's `jit_run`).  Per program key (family, k,
 construction, mode, batch, shards) the ledger records:
 
     compile_s          wall-seconds of the FIRST dispatch (jax traces +
@@ -239,6 +241,19 @@ class _Tracked:
         return getattr(self._fn, name)
 
 
+def _name_program(fn, family: str) -> None:
+    """Name the program after its family on the device trace.  jit names
+    the module after the function it wraps (`jit_<__name__>`), read when
+    the program is first traced — so renaming the builder's freshly built
+    function here, before its first call, gives the trace
+    `jit_<family>(<fingerprint>)`: a name that survives any change to the
+    program's body, where every builder's inner `run` would read
+    `jit_run`."""
+    inner = getattr(fn, "__wrapped__", None)
+    if inner is not None and getattr(inner, "__name__", family) != family:
+        inner.__name__ = family
+
+
 def track(fn, family: str, *, k=None, construction=None, mode=None,
           batch=None, shards=None):
     """Register a freshly built jit program under (family, k,
@@ -248,6 +263,7 @@ def track(fn, family: str, *, k=None, construction=None, mode=None,
     nothing.  Rebuilding an evicted key revives the same stats record —
     compile_s then accumulates the re-compile bill too."""
     key = _key(family, k, construction, mode, batch, shards)
+    _name_program(fn, family)
     with _LOCK:
         rec = _PROGRAMS.get(key)
         if rec is None:

@@ -147,8 +147,8 @@ def rss_high_water() -> int | None:
 
 def record_hbm_high_water(point: str = "dispatch",
                           k: int | None = None) -> int | None:
-    """Refresh celestia_hbm_peak_bytes{point,k,source} and journal the
-    sample; returns the peak bytes.  Device allocator stats when the
+    """Refresh celestia_hbm_peak_bytes{point,k,source}; returns the peak
+    bytes.  Device allocator stats when the
     backend keeps them (source="device"), else the process peak RSS
     (source="rss") so the high-water stays measurable on CPU images;
     None only when neither source can answer."""
@@ -158,7 +158,6 @@ def record_hbm_high_water(point: str = "dispatch",
     if peak is None:
         return None
     from celestia_app_tpu.trace.metrics import registry
-    from celestia_app_tpu.trace.tracer import traced
 
     labels = {"point": point, "source": source}
     if k is not None:
@@ -168,6 +167,4 @@ def record_hbm_high_water(point: str = "dispatch",
         "memory high-water mark (device allocator peak_bytes_in_use, or "
         "process peak RSS on stat-less backends — see the source label)",
     ).set(peak, **labels)
-    traced().write("hbm_high_water", point=point, k=k, peak_bytes=peak,
-                   source=source)
     return peak

@@ -66,7 +66,8 @@ def record_span(
     attributes: dict,
 ) -> None:
     """Export one finished span: OTLP-shaped row into the spans table,
-    plus the env-gated JSONL mirror."""
+    plus the env-gated JSONL mirror.  The caller has checked the
+    $CELESTIA_TRACE gate."""
     from celestia_app_tpu.trace.tracer import traced
 
     row = {
@@ -82,7 +83,7 @@ def record_span(
             if v is not None
         ],
     }
-    traced().write(SPANS_TABLE, **row)
+    traced().append(SPANS_TABLE, row)
     _mirror_to_file(row)
 
 

@@ -13,8 +13,10 @@ table puller consumes (test/e2e/testnet/node.go:52-74); the serving planes
 expose it live on GET /trace_tables (trace/exposition.py).
 
 The tracer is written to from the block pipeline's uploader/dispatcher
-threads concurrently with serving-plane readers, so every table mutation
-holds `_lock`; buffer eviction is counted in the Prometheus counter
+threads and the serving plane's workers concurrently with readers: each
+table is a bounded deque whose append (and a reader's copy) is one
+atomic step under the GIL, and `_lock` serializes only the readers and
+`clear`; buffer eviction is counted in the Prometheus counter
 `celestia_trace_rows_dropped` instead of disappearing silently.
 
 $CELESTIA_TRACE=off gates the whole layer: writes and span observations
@@ -28,7 +30,9 @@ import json
 import os
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
+from itertools import islice
 
 from celestia_app_tpu.trace.metrics import registry
 
@@ -47,7 +51,8 @@ def trace_enabled() -> bool:
 class Tracer:
     def __init__(self, buffer_size: int = 10_000, env_gated: bool = True):
         self.buffer_size = buffer_size
-        self._tables: dict[str, list[dict]] = {}
+        # One ring per table: a full ring drops its oldest row in O(1).
+        self._tables: dict[str, deque] = {}
         self._lock = threading.Lock()
         self.enabled = True
         # env_gated=False opts a PRIVATE tracer out of $CELESTIA_TRACE:
@@ -57,6 +62,7 @@ class Tracer:
         # Row observers (trace/timeline.py's height stitcher): called
         # with (table, row) after every write, outside the table lock.
         self._observers: list = []
+        self._drops = None  # celestia_trace_rows_dropped, once looked up
 
     def add_observer(self, fn) -> None:
         """Subscribe `fn(table, row)` to every row written through this
@@ -70,32 +76,41 @@ class Tracer:
         return self.enabled and (not self.env_gated or trace_enabled())
 
     def write(self, table: str, **row) -> None:
-        if not self._on():
-            return
+        if self._on():
+            self.append(table, row)
+
+    def append(self, table: str, row: dict) -> None:
+        """`write` for a caller that has checked the gate itself (the span
+        export writes two rows per span on the serving hot path)."""
         # Every row says WHICH node wrote it: in a shared-artifact
         # multi-node drill (one $CELESTIA_FLIGHT_DIR, merged table pulls)
-        # provenance must ride the row, not the transport.  Lazy import:
-        # context.py imports from this module.
+        # provenance must ride the row, not the transport.
         from celestia_app_tpu.trace.context import node_id
 
-        dropped = 0
-        with self._lock:
-            rows = self._tables.setdefault(table, [])
-            stamped = {"ts_ns": time.time_ns(), "node_id": node_id(), **row}
-            rows.append(stamped)
-            if len(rows) > self.buffer_size:
-                dropped = len(rows) - self.buffer_size
-                del rows[:dropped]
+        stamped = {"ts_ns": time.time_ns(), "node_id": node_id(), **row}
+        # No lock on the append: a deque append, a dict setdefault and a
+        # reader's list(deque) are each one atomic step under the GIL, and
+        # sixteen serving threads writing two rows per span would queue
+        # on one lock.
+        rows = self._tables.get(table)
+        if rows is None:
+            rows = self._tables.setdefault(
+                table, deque(maxlen=self.buffer_size)
+            )
+        dropped = len(rows) == rows.maxlen
+        rows.append(stamped)
         for obs in self._observers:
             try:
                 obs(table, stamped)
             except Exception:  # chaos-ok: observers must never fail a write
                 pass
         if dropped:
-            registry().counter(
-                "celestia_trace_rows_dropped",
-                "trace table rows evicted by the ring buffer",
-            ).inc(dropped, table=table)
+            if self._drops is None:
+                self._drops = registry().counter(
+                    "celestia_trace_rows_dropped",
+                    "trace table rows evicted by the ring buffer",
+                )
+            self._drops.inc(1, table=table)
 
     @contextmanager
     def span(self, table: str, *, buckets: tuple[float, ...] | None = None,
@@ -106,23 +121,28 @@ class Tracer:
         (SPAN_LABEL_ATTRS, e.g. k=...) as labels.  Device-scale call sites
         pass an explicit `buckets` tuple (metrics.DEVICE_SECONDS_BUCKETS);
         the histogram lookup happens on entry, off the timed region and out
-        of the finally block.
+        of the finally block.  Opens through trace/context.SpanClock, like
+        every span: the profiler annotation, the row's `start_ns`/`end_ns`
+        and `cpu_ms`.
         """
+        from celestia_app_tpu.trace.context import SpanClock
+
         if not self._on():
-            yield
+            with SpanClock(table, timed=False):
+                yield
             return
         hist = registry().histogram(
             f"celestia_{table}_seconds", f"wall time of {table}",
             **({"buckets": buckets} if buckets else {}),
         )
         labels = {a: str(attrs[a]) for a in SPAN_LABEL_ATTRS if a in attrs}
-        start = time.perf_counter_ns()
+        clock = SpanClock(table)
         try:
-            yield
+            with clock:
+                yield
         finally:
-            elapsed_ns = time.perf_counter_ns() - start
-            self.write(table, duration_ms=elapsed_ns / 1e6, **attrs)
-            hist.observe(elapsed_ns / 1e9, **labels)
+            self.write(table, **clock.row_fields(), **attrs)
+            hist.observe(clock.elapsed_ns / 1e9, **labels)
 
     def table(self, name: str) -> list[dict]:
         with self._lock:
@@ -144,8 +164,8 @@ class Tracer:
         if n <= 0:
             return []
         with self._lock:
-            rows = self._tables.get(name, [])
-            return list(rows[-n:])
+            rows = self._tables.get(name, ())
+            return list(islice(rows, max(0, len(rows) - n), None))
 
     def export_jsonl(self, name: str, tail: int | None = None) -> str:
         # Delegate the tail slice so the two accessors cannot diverge

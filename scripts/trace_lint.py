@@ -122,7 +122,6 @@ HEIGHT_FREE_TABLES = {
     "wal_salvage",
     "chaos_injection",
     "profiler",        # one capture window per process, not per height
-    "hbm_high_water",  # lifetime allocator/RSS peaks, not per height
 }
 
 

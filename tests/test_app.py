@@ -291,3 +291,95 @@ class TestMultiSend:
         )
         res, _ = self._submit(node, key, two_senders, seq=0)
         assert res.code != 0 and "multiple senders" in res.log
+
+
+class TestLayerSpans:
+    """A height's host phases as spans: a proposer App prepares, a second
+    App (a validator that never saw the square) processes, both finalize
+    and commit.  Every span row carries the height and the side."""
+
+    CHILDREN = ("share_pack", "square_digest", "ods_upload",
+                "extend_dispatch", "roots_wait")
+
+    since = 0  # rows of earlier tests (same heights) are left out
+
+    def _rows(self, name: str, height: int,
+              phase: str | None = None) -> list[dict]:
+        from celestia_app_tpu.trace.tracer import traced
+
+        return [r for r in traced().table(name) if r.get("height") == height
+                and r.get("start_ns", r["ts_ns"]) >= self.since
+                and (phase is None or r.get("phase") == phase)]
+
+    def test_prepare_and_process_write_every_layer_span(self, tmp_path):
+        import time
+
+        import jax
+
+        self.since = time.time_ns()
+        proposer, validator = TestNode(), TestNode()
+        key = proposer.keys[0]
+        blobs = (Blob(user_ns(9), rand_bytes(6000)),
+                 Blob(user_ns(10), rand_bytes(900)))
+        raw = pfb_tx(proposer, key, blobs, seq=0)
+        # Under a profiler session, as a traced run: rows carry cpu_ms.
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            data = proposer.app.prepare_proposal([raw])
+            assert 1 < data.square_size <= 8
+            assert validator.app.process_proposal(data)
+            height = proposer.app.height + 1
+            for app in (proposer.app, validator.app):
+                app.finalize_block(proposer.app.last_block_time_ns + 10**9,
+                                   list(data.txs))
+                app.commit()
+        finally:
+            jax.profiler.stop_trace()
+
+        for phase in ("prepare", "process"):
+            for name in ("ante", "blob_validate", "square_pipeline",
+                         *self.CHILDREN):
+                rows = self._rows(name, height, phase)
+                assert rows, (name, phase)
+                for r in rows:
+                    assert 0 <= r["cpu_ms"] <= r["duration_ms"] + 1e-3
+            [pipe] = self._rows("square_pipeline", height, phase)
+            assert pipe["memo"] == "miss"
+            children = sum(r["duration_ms"] for name in self.CHILDREN
+                           for r in self._rows(name, height, phase))
+            assert 0 < children <= pipe["duration_ms"]
+            # Every child lies inside the parent's interval.
+            for name in self.CHILDREN:
+                for r in self._rows(name, height, phase):
+                    assert pipe["start_ns"] <= r["start_ns"] <= r["end_ns"] \
+                        <= pipe["end_ns"]
+        assert self._rows("square_build", height, "prepare")
+        assert self._rows("square_construct", height, "process")
+        [proposed] = self._rows("blob_validate", height, "prepare")
+        [checked] = self._rows("blob_validate", height, "process")
+        assert proposed["n_blobs"] == checked["n_blobs"] == 2
+        # The validator's commitments come from the process-wide memo the
+        # proposer filled (both Apps share one process).
+        assert checked["memo_hits"] == 2
+        assert len(self._rows("finalize_block", height)) == 2
+        assert len(self._rows("commit", height)) == 2
+        # The journal's upload/dispatch timings are the spans' own.
+        from celestia_app_tpu.trace.tracer import traced
+
+        journal = [r for r in traced().table("block_journal")
+                   if r.get("height") == height and r["source"] == "compute"
+                   and r["ts_ns"] >= self.since]
+        uploads = sorted(r["duration_ms"]
+                         for r in self._rows("ods_upload", height))
+        assert sorted(r["upload_ms"] for r in journal) == uploads
+
+    def test_own_root_memo_hit_is_marked(self, node):
+        import time
+
+        self.since = time.time_ns()
+        key = node.keys[0]
+        data = node.app.prepare_proposal(
+            [pfb_tx(node, key, (Blob(user_ns(11), rand_bytes(700)),), seq=0)])
+        assert node.app.process_proposal(data)
+        [pipe] = self._rows("square_pipeline", node.app.height + 1, "process")
+        assert pipe["memo"] == "hit"

@@ -77,6 +77,32 @@ class TestRealPipelineTick:
         assert forest["resident"] is True  # lru builder still holds it
         assert snap["programs_resident"]["forest"] >= 1
 
+    def test_programs_are_named_after_their_family(self):
+        """The device trace names a program `jit_<family>`, never the
+        builder closure's `jit_run`: a name that outlives its
+        fingerprint."""
+        import jax.numpy as jnp
+
+        from celestia_app_tpu.kernels import fused
+        from celestia_app_tpu.kernels.rs import active_construction
+
+        ods = jnp.asarray(det_square(4))
+        fn = fused.jit_extend_and_dah(4, active_construction())
+        eds = fn(ods)[0]
+        lowered = {
+            "extend_and_dah": fn.lower(ods).as_text(),
+            "forest": fused.jit_forest(4).lower(eds).as_text(),
+        }
+        for family, text in lowered.items():
+            assert text.startswith(f"module @jit_{family} "), text[:80]
+        live = [rec for rec in dl._PROGRAMS.values()
+                if rec["ref"] is not None and rec["ref"]() is not None]
+        assert {"extend_and_dah", "forest"} <= {r["family"] for r in live}
+        for rec in live:
+            inner = getattr(rec["ref"]()._fn, "__wrapped__", None)
+            if inner is not None:
+                assert inner.__name__ == rec["family"]
+
     def test_snapshot_rows_are_sorted_and_shaped(self):
         snap = dl.snapshot()
         keys = [

@@ -159,6 +159,94 @@ class TestSamplerQueue:
             ProofSampler().sample_batch(entry, [(0, 0), (8, 0)])
 
 
+class TestServeSpans:
+    """The serve path's steps as spans: gather and assembly per batch
+    group (with its tier), encoding per sample, and each group's summed
+    queue wait on its `proof_serve` row."""
+
+    def test_gather_assemble_encode_rows_and_queue_wait(self, tmp_path):
+        import time
+
+        import jax
+
+        from celestia_app_tpu.trace.tracer import traced
+
+        since = time.time_ns()
+        provider = DasProvider(cache=ForestCache(heights=1, spill=1))
+        provider.cache.put(5, make_eds(k=4, seed=41))
+        provider.cache.put(6, make_eds(k=4, seed=42))  # height 5 spills
+        provider.share_proof_payload(6, 1, 2)  # warm, outside the profile
+        # Under a profiler session, as a traced run: rows carry cpu_ms.
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            since = time.time_ns()
+            provider.share_proof_payload(6, 1, 2)
+            provider.share_proof_payload(5, 3, 0)
+        finally:
+            jax.profiler.stop_trace()
+
+        def rows(name):
+            return [r for r in traced().table(name)
+                    if r.get("start_ns", r["ts_ns"]) >= since]
+
+        gather, assemble, encode = (rows("proof_gather"),
+                                    rows("proof_assemble"),
+                                    rows("proof_encode"))
+        assert [r["tier"] for r in gather] == ["device", "host"]
+        assert [r["batch"] for r in gather] == [1, 1]
+        assert [r["batch"] for r in assemble] == [1, 1]
+        assert len(encode) == 2
+        for r in gather + assemble + encode:
+            assert 0 <= r["cpu_ms"] <= r["duration_ms"] + 1e-3
+            assert r["end_ns"] >= r["start_ns"]
+        serve = rows("proof_serve")
+        assert len(serve) == 2
+        assert all(r["queue_wait_ms"] >= 0 for r in serve)
+        # Outside a profiler session no thread CPU clock is read.
+        provider.share_proof_payload(6, 2, 2)
+        assert "cpu_ms" not in traced().table("proof_encode")[-1]
+
+    def test_serve_spans_join_a_request_trace_only(self):
+        """Outside a request's trace the serve steps write their rows but
+        no OTLP rows; inside one they hang under the request's span."""
+        import time
+
+        from celestia_app_tpu.trace.context import new_context, use_context
+        from celestia_app_tpu.trace.spans import SPANS_TABLE
+        from celestia_app_tpu.trace.tracer import traced
+
+        steps = {"proof_gather", "proof_assemble", "proof_encode"}
+        provider = DasProvider(cache=ForestCache(heights=1, spill=1))
+        provider.cache.put(7, make_eds(k=4, seed=44))
+        since = time.time_ns()
+        provider.share_proof_payload(7, 0, 1)
+        assert not [r for r in traced().table(SPANS_TABLE)
+                    if r["ts_ns"] >= since and r["name"] in steps]
+        assert "trace_id" not in traced().table("proof_encode")[-1]
+        ctx = new_context()
+        with use_context(ctx):
+            provider.share_proof_payload(7, 1, 1)
+        joined = [r for r in traced().table(SPANS_TABLE)
+                  if r["traceId"] == ctx.trace_id]
+        assert {r["name"] for r in joined} == steps
+        assert {r["parentSpanId"] for r in joined} == {ctx.span_id}
+
+    def test_queue_wait_sums_the_group(self):
+        from celestia_app_tpu.serve import sampler as sampler_mod
+        from celestia_app_tpu.trace.tracer import traced
+
+        cache = ForestCache(heights=1, spill=1)
+        entry = cache.put(1, make_eds(k=4, seed=43))
+        pending = [sampler_mod._Pending(entry, i, i, "row") for i in range(3)]
+        for i, p in enumerate(pending):
+            p.t_submit -= 0.010 * (i + 1)  # waited 10, 20 and 30 ms
+        ProofSampler()._serve_batch(pending)
+        row = traced().table("proof_serve")[-1]
+        assert row["batch"] == 3
+        assert 60.0 <= row["queue_wait_ms"] < 60.0 + 3 * 50.0
+        assert all(p.proof is not None for p in pending)
+
+
 class TestChaosFallback:
     def test_injected_proof_fault_served_by_host_path_bit_identical(self):
         from celestia_app_tpu import chaos
@@ -504,7 +592,13 @@ class TestSloAndHealth:
             dict(key).get("phase")
             for key, _ in hist.snapshot().children.items()
         }
-        assert {"queue_wait", "gather", "assemble", "total"} <= phases
+        # Only the per-sample total is left on the latency family; the
+        # steps are spans, each with its own histogram.
+        assert phases == {"total"}
+        for step in ("gather", "assemble"):
+            assert registry().get(
+                f"celestia_proof_{step}_seconds"
+            ).snapshot().children
 
 
 class TestLoadgenSmoke:

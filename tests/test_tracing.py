@@ -123,6 +123,159 @@ class TestTraceContext:
         assert any(r["traceId"] == ctx.trace_id for r in rows)
 
 
+def _record_profile(tmp_path, body) -> tuple[int, dict[str, list]]:
+    """Run `body()` under a jax.profiler session; return the session's
+    start on the host clock and {host event name: [(start_ns, end_ns)]}
+    in absolute ns (events are offsets from `profile_start_time`)."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = max(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    data = ProfileData.from_file(path)
+    start = None
+    events: dict[str, list] = {}
+    for plane in data.planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                start = int(value)
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        (int(e.start_ns), int(e.start_ns + e.duration_ns)))
+    assert start is not None
+    return start, {n: [(start + a, start + b) for a, b in iv]
+                   for n, iv in events.items()}
+
+
+class TestSpansOnTheProfilerClock:
+    """Every span is a profiler annotation under its bare name, and its
+    row's start/end sit on the clock the profiler stamps host events
+    with."""
+
+    def test_nested_spans_match_their_host_events(self, tmp_path):
+        def body():
+            with trace_span("clock_outer_span"):
+                sum(range(20_000))
+                with trace_span("clock_inner_span", k=2):
+                    sum(range(50_000))
+
+        _, events = _record_profile(tmp_path, body)
+        for name in ("clock_outer_span", "clock_inner_span"):
+            row = traced().table(name)[-1]
+            [(lo, hi)] = events[name]
+            assert abs(row["start_ns"] - lo) <= 100_000, (name, row, lo)
+            assert abs(row["end_ns"] - hi) <= 100_000, (name, row, hi)
+            assert 0 <= row["cpu_ms"] <= row["duration_ms"]
+            assert row["duration_ms"] == pytest.approx(
+                (row["end_ns"] - row["start_ns"]) / 1e6)
+        outer = traced().table("clock_outer_span")[-1]
+        inner = traced().table("clock_inner_span")[-1]
+        assert outer["start_ns"] <= inner["start_ns"] <= inner["end_ns"] \
+            <= outer["end_ns"]
+
+    def test_tracer_span_and_muted_spans_annotate_too(self, tmp_path,
+                                                      monkeypatch):
+        def body():
+            with traced().span("clock_tracer_span", k=4):
+                sum(range(10_000))
+            monkeypatch.setenv("CELESTIA_TRACE", "off")
+            with trace_span("clock_muted_span"):
+                pass
+            monkeypatch.delenv("CELESTIA_TRACE")
+
+        _, events = _record_profile(tmp_path, body)
+        assert "clock_tracer_span" in events and "clock_muted_span" in events
+        row = traced().table("clock_tracer_span")[-1]
+        [(lo, hi)] = events["clock_tracer_span"]
+        assert abs(row["start_ns"] - lo) <= 100_000
+        assert abs(row["end_ns"] - hi) <= 100_000
+        assert 0 <= row["cpu_ms"] <= row["duration_ms"]
+        assert traced().table("clock_muted_span") == []
+
+    def test_duration_is_timed_on_the_monotonic_clock(self, monkeypatch):
+        """A wall-clock step inside the body bends neither the duration
+        nor the order of start and end."""
+        import time
+
+        ctx = new_context()
+        stamps = iter([2_000_000_000_000_000_000, 1_000_000_000_000_000_000])
+        real = time.time_ns
+        monkeypatch.setattr(time, "time_ns", lambda: next(stamps, real()))
+        with trace_span("clock_stepped_span", ctx=ctx):
+            pass
+        row = traced().table("clock_stepped_span")[-1]
+        assert row["start_ns"] == 2_000_000_000_000_000_000
+        assert 0 <= row["duration_ms"] < 1_000
+        assert row["end_ns"] == row["start_ns"] + round(
+            row["duration_ms"] * 1e6)
+
+    def test_rootless_span_outside_a_trace_writes_no_otlp_row(self):
+        with trace_span("clock_rootless_span", root=False, batch=3):
+            assert current_context() is None
+        row = traced().table("clock_rootless_span")[-1]
+        assert row["batch"] == 3 and row["end_ns"] >= row["start_ns"]
+        assert "trace_id" not in row
+        assert not [r for r in traced().table(SPANS_TABLE)
+                    if r["name"] == "clock_rootless_span"]
+        assert registry().get(
+            "celestia_clock_rootless_span_seconds").snapshot().children
+
+    def test_rootless_span_inside_a_trace_joins_it(self):
+        ctx = new_context()
+        with use_context(ctx):
+            with trace_span("clock_joined_span", root=False):
+                assert current_context().parent_id == ctx.span_id
+        row = traced().table("clock_joined_span")[-1]
+        assert row["trace_id"] == ctx.trace_id
+        [otlp] = [r for r in _spans_for(ctx.trace_id)
+                  if r["name"] == "clock_joined_span"]
+        assert otlp["parentSpanId"] == ctx.span_id
+
+    def test_cpu_clock_only_while_a_profiler_records(self):
+        with trace_span("clock_unprofiled_span"):
+            pass
+        row = traced().table("clock_unprofiled_span")[-1]
+        assert "cpu_ms" not in row and row["end_ns"] >= row["start_ns"]
+
+    def test_baggage_rides_child_rows(self):
+        with trace_span("clock_parent_span",
+                        baggage={"height": 41, "phase": "process"}) as sp:
+            with trace_span("clock_child_span"):
+                pass
+        child = traced().table("clock_child_span")[-1]
+        assert child["height"] == 41 and child["phase"] == "process"
+        # After the body the span's own measurement is readable.
+        assert sp["duration_ms"] >= child["duration_ms"]
+
+    def test_one_span_primitive(self):
+        """TraceAnnotation is entered in one place: trace/context.py."""
+        import os
+
+        pkg = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "celestia_app_tpu")
+        users = []
+        for dirpath, _, files in os.walk(pkg):
+            for fn in files:
+                if fn.endswith(".py"):
+                    path = os.path.join(dirpath, fn)
+                    with open(path, encoding="utf-8") as f:
+                        if "TraceAnnotation" in f.read():
+                            users.append(os.path.relpath(path, pkg))
+        assert users == [os.path.join("trace", "context.py")]
+
+
 class TestMempoolTracing:
     def _tx(self, i: int, size: int = 8) -> bytes:
         return bytes([i]) * size
