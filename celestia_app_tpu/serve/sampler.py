@@ -7,8 +7,9 @@ share (row, col) against the committed DAH root", pinned byte-identical:
   batched (default)  the index plan for every queued request is computed
                      host-side (range_proof_node_coords — pure int math),
                      then the whole batch's proof nodes and shares come
-                     off the cached forest in ONE gather per array
-                     (serve/cache.CachedForest.gather), and RowProof
+                     off the cached forest and square in ONE compiled
+                     gather program and one readback
+                     (serve/cache.CachedForest.gather_proof), and RowProof
                      audit paths are indexed out of the memoized
                      data-root tree levels.  Zero hashing per request.
   host (fallback)    rebuild the touched row's NMT from the retained
@@ -410,7 +411,8 @@ class ProofSampler:
         n = 2 * entry.k
         tier = "device" if getattr(entry, "device_resident", True) else "host"
         with trace_span("proof_gather", root=False, layer="serve",
-                        batch=len(coords), tier=tier):
+                        batch=len(coords), tier=tier,
+                        programs=entry.gather_programs):
             # Row sampling proves leaf `col` of tree `row`; column
             # sampling the transpose — leaf `row` of column tree `col`,
             # whose root is data-root leaf 2k + col.
@@ -425,8 +427,7 @@ class ProofSampler:
                 node_idx.extend(
                     entry.flat_index(tree, lvl, i) for lvl, i in plan
                 )
-            nodes = entry.gather(axis, node_idx)
-            shares = entry.gather_shares(coords)
+            nodes, shares = entry.gather_proof(axis, node_idx, coords)
 
         from celestia_app_tpu import merkle
 

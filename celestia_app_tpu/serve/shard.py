@@ -54,7 +54,7 @@ from celestia_app_tpu.parallel.mesh import (
     sharded_gather_fn,
     sharded_share_gather_fn,
 )
-from celestia_app_tpu.serve.cache import CachedForest
+from celestia_app_tpu.serve.cache import FOREST_ROW, CachedForest
 
 
 def serve_shards() -> int:
@@ -281,6 +281,16 @@ class ShardedCachedForest(CachedForest):
             recoveries().inc(seam="proof.shard", outcome="degraded")
             return super().gather(axis, flat_indices)
 
+    @property
+    def gather_programs(self) -> int:
+        return 2 if self.device_resident else 0
+
+    def gather_proof(self, axis: str, flat_indices, coords
+                     ) -> tuple[np.ndarray, np.ndarray]:
+        """Nodes through the sharded forest program, shares through
+        their own take: the sharded plane's two dispatches."""
+        return self.gather(axis, flat_indices), self.gather_shares(coords)
+
     # --- introspection -------------------------------------------------------
     def shard_resident_bytes(self) -> dict[str, int]:
         """Per-shard resident forest bytes (both axes) — the /healthz
@@ -297,7 +307,8 @@ def build_entry(height: int, eds) -> CachedForest:
     $CELESTIA_SERVE_SHARDS > 1 routes the forest build through the
     sharded program (committed out_shardings — laid out once, here) and
     wraps the entry as ShardedCachedForest; otherwise the single-device
-    build, byte-identical.
+    build, byte-identical, its forests widened once, here, to rows of
+    serve/cache.FOREST_ROW bytes, so no gather ever relayouts them.
     """
     import jax.numpy as jnp
 
@@ -313,7 +324,10 @@ def build_entry(height: int, eds) -> CachedForest:
     from celestia_app_tpu.kernels.fused import jit_forest
 
     row_flat, col_flat = jit_forest(eds.k)(jnp.asarray(eds._eds))
-    return CachedForest(height, eds, row_flat, col_flat)
+    pad = ((0, 0), (0, FOREST_ROW - row_flat.shape[1]))
+    return CachedForest(
+        height, eds, jnp.pad(row_flat, pad), jnp.pad(col_flat, pad)
+    )
 
 
 # Per-cache contributions to the process-wide resident-bytes gauge:

@@ -12,6 +12,7 @@ out of this tier.
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -129,3 +130,37 @@ def test_rs_xor_extend_leaf_digests(one_chip):
         (K, 2 * K, SHARE_SIZE), (K, 2 * K, 32)
     ]
     assert np.dtype(out[1].dtype) == np.uint8
+
+
+@pytest.mark.parametrize("nodes, shares", [(8, 1), (128, 16), (512, 0), (0, 16)])
+def test_serve_gather_reads_the_resident_arrays(one_chip, nodes, shares):
+    """The DAS serve gather at the hard-cap square (k=128), over the
+    resident forest and square in their default layouts: no op copies
+    or prefetches the whole EDS or the whole forest — each dispatch
+    reads only its rows."""
+    from celestia_app_tpu.constants import SHARE_SIZE
+    from celestia_app_tpu.serve.cache import FOREST_ROW, take_fn
+
+    k, n = 128, 256
+    forest = (n * (2 * n - 1), FOREST_ROW)
+    square = (n, n, SHARE_SIZE)
+    compiled = take_fn(k, nodes, shares, "tpu").lower(
+        _spec(one_chip, forest, jnp.uint8) if nodes else None,
+        _spec(one_chip, square, jnp.uint8) if shares else None,
+        _spec(one_chip, (nodes + 2 * shares,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "cross_program_prefetch" not in text
+    big = [f"u8[{','.join(map(str, s))}]" for s in (forest, square)]
+    whole = []
+    for line in text.splitlines():
+        _, eq, rhs = line.partition(" = ")
+        op = re.search(r"\s([a-z][\w-]*)\(", rhs)
+        if eq and op and op.group(1) != "parameter" and any(
+            b in rhs[:op.start()] for b in big
+        ):
+            whole.append(line.strip())
+    assert not whole, whole[:2]
+    assert [tuple(o.shape) for o in [compiled.out_info]] == [
+        (nodes * 90 + shares * SHARE_SIZE,)
+    ]

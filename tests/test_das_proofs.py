@@ -205,26 +205,47 @@ class TestNamespaceRanges:
         assert len(eds._tree_memo) == after_first  # second query: all memo
 
 
+#: (k, construction, batch, axis): the 12-draw sets on both axes, then
+#: every gather bucket up to 16 samples and both edges of its padding.
+_BATCHED_CASES = [
+    pytest.param(k, c, None, axis,
+                 id=f"{c}-{k}" + ("" if axis == "row" else "-col"))
+    for axis in ("row", "col") for c in CONSTRUCTIONS for k in (2, 8)
+] + [
+    pytest.param(8, c, batch, axis, id=f"{c}-8-b{batch}-{axis}")
+    for c in CONSTRUCTIONS for batch in (1, 2, 7, 8, 9, 16)
+    for axis in ("row", "col")
+]
+
+
 class TestBatchedHostIdentity:
     """The serve plane's exactness seam: forest gathers vs host rebuild."""
 
-    @pytest.mark.parametrize("k", [2, 8])
-    @pytest.mark.parametrize("construction", CONSTRUCTIONS)
-    def test_batched_equals_host_bit_for_bit(self, squares, k, construction):
+    @pytest.mark.parametrize("k, construction, batch, axis", _BATCHED_CASES)
+    def test_batched_equals_host_bit_for_bit(
+        self, squares, k, construction, batch, axis
+    ):
         eds = squares(k, construction)
         cache = ForestCache(heights=8, spill=8)
         entry = cache.put((k, CONSTRUCTIONS.index(construction)), eds)
         sampler = ProofSampler()
-        rng = np.random.default_rng(k)
         n = 2 * k
-        coords = sorted({
-            (int(rng.integers(0, n)), int(rng.integers(0, n)))
-            for _ in range(12)
-        })
-        batched = sampler.sample_batch(entry, coords)
+        if batch is None:
+            rng = np.random.default_rng(k)
+            coords = sorted({
+                (int(rng.integers(0, n)), int(rng.integers(0, n)))
+                for _ in range(12)
+            })
+        else:
+            picks = np.random.default_rng([k, batch]).choice(
+                n * n, batch, replace=False
+            )
+            coords = [(int(i) // n, int(i) % n) for i in picks]
+        batched = sampler.sample_batch(entry, coords, axis=axis)
+        assert len(batched) == len(coords)
         root = eds.data_root()
         for (row, col), proof in zip(coords, batched):
-            host = sampler.host_proof(entry, row, col)
+            host = sampler.host_proof(entry, row, col, axis)
             assert proof == host, (k, construction, row, col)
             assert render(to_jsonable(proof)) == render(to_jsonable(host))
             assert proof.verify(root)
@@ -242,6 +263,95 @@ class TestBatchedHostIdentity:
         assert not entry.device_resident
         host_tier_proofs = sampler.sample_batch(entry, [(0, 0), (9, 13)])
         assert host_tier_proofs == device_proofs
+
+
+def _serve_entry(spilled: bool):
+    """A k=8 entry on the device tier, or spilled to the host tier, and
+    its square."""
+    eds = ExtendedDataSquare.compute(det_square(8, seed=12))
+    cache = ForestCache(heights=1, spill=2)
+    entry = cache.put(1, eds)
+    if spilled:
+        cache.put(2, ExtendedDataSquare.compute(det_square(8, seed=13)))
+        assert cache.get(1) == (entry, "host")
+    return entry, eds
+
+
+class TestServeGather:
+    """The compiled serve gather: what every reader of a retained height
+    gets back, and what one sampled batch dispatches."""
+
+    @pytest.mark.parametrize("tier", ["device", "host"])
+    def test_gathers_return_the_resident_bytes(self, tier):
+        entry, eds = _serve_entry(spilled=tier == "host")
+        assert entry.device_resident == (tier == "device")
+        n = 2 * entry.k
+        square = np.asarray(eds._eds)
+        for axis in ("row", "col"):
+            forest = np.asarray(entry._flat(axis))[:, :90]
+            for count in (0, 1, 7, 8, 9, 2 * n - 1):
+                idx = [(i * 37) % forest.shape[0] for i in range(count)]
+                got = entry.gather(axis, idx)
+                assert got.shape == (count, 90) and got.dtype == np.uint8
+                assert np.array_equal(got, forest[idx])
+            for tree in (0, n - 1):
+                host = (eds.row_tree(tree, host=True) if axis == "row"
+                        else eds.col_tree(tree, host=True)).levels()
+                assert entry.line_levels(axis, tree) == host
+                leaves = entry.gather(axis, [
+                    entry.flat_index(tree, 0, i) for i in range(n)
+                ])
+                assert [bytes(x.tobytes()) for x in leaves] == host[0]
+        for count in (0, 1, 3, 16, 17):
+            coords = [((i * 5) % n, (i * 11) % n) for i in range(count)]
+            got = entry.gather_shares(coords)
+            assert got.shape == (count, SHARE_SIZE)
+            want = np.asarray([square[r, c] for r, c in coords], np.uint8)
+            assert np.array_equal(got, want.reshape(count, SHARE_SIZE))
+        nodes, shares = entry.gather_proof("col", [3, 5], [(1, 2)])
+        assert np.array_equal(nodes, np.asarray(entry.col_flat)[[3, 5], :90])
+        assert np.array_equal(shares, square[[1], [2]])
+
+    def test_one_program_per_batch_and_no_recompile_in_a_bucket(
+        self, monkeypatch
+    ):
+        import time
+
+        from celestia_app_tpu.serve import cache as cache_mod
+        from celestia_app_tpu.trace.tracer import traced
+
+        built = cache_mod.take_fn
+        calls: list[tuple] = []
+
+        def counting(*key):
+            fn = built(*key)
+
+            def dispatch(*args):
+                calls.append(key)
+                return fn(*args)
+
+            return dispatch
+
+        monkeypatch.setattr(cache_mod, "take_fn", counting)
+        entry, _ = _serve_entry(spilled=False)
+        sampler = ProofSampler()
+        since = time.time_ns()
+        sampler.sample_batch(entry, [(0, 1), (2, 3), (4, 5)])
+        assert len(calls) == 1
+        key = calls[0]
+        assert key[1:3] == (4 * 4, 4)  # 4 levels x bucket 4, bucket 4
+        compiled = built(*key)._cache_size()
+        for coords in ([(6, 7), (8, 9), (1, 1), (15, 0)], [(3, 3)] * 3):
+            sampler.sample_batch(entry, coords, axis="col")
+        assert calls == [key] * 3
+        assert built(*key)._cache_size() == compiled
+        rows = [r for r in traced().table("proof_gather")
+                if r.get("start_ns", r["ts_ns"]) >= since]
+        assert [(r["tier"], r["programs"]) for r in rows] == [("device", 1)] * 3
+        spilled, _ = _serve_entry(spilled=True)
+        sampler.sample_batch(spilled, [(0, 1)])
+        assert len(calls) == 3  # the host tier dispatches nothing
+        assert traced().table("proof_gather")[-1]["programs"] == 0
 
 
 class TestGoldenPins:
