@@ -142,27 +142,33 @@ _COVERAGE: OrderedDict[int, "CoverageMap"] = OrderedDict()
 
 class CoverageMap:
     """Per-height coordinate state grid over the EXTENDED square (2k x
-    2k), one byte per cell holding the state rank."""
+    2k), one byte per cell holding the state rank, plus the number of
+    cells at each rank, kept as ticks raise cells so that a tick,
+    `counts()` and `ratio()` cost O(coordinates ticked), never a scan
+    of the (2k)^2 map (they run under `_COVERAGE_LOCK` once per served
+    sample)."""
 
     def __init__(self, height: int, k: int):
         self.height = height
         self.k = k
         self.cells = bytearray((2 * k) * (2 * k))
+        self.tally = [len(self.cells)] + [0] * len(COVERAGE_STATES)
 
     def tick(self, coords, state: str) -> None:
         rank = _STATE_RANK[state]
         n = 2 * self.k
+        cells, tally = self.cells, self.tally
         for row, col in coords:
             if 0 <= row < n and 0 <= col < n:
                 i = row * n + col
-                if rank > self.cells[i]:
-                    self.cells[i] = rank
+                old = cells[i]
+                if rank > old:
+                    cells[i] = rank
+                    tally[old] -= 1
+                    tally[rank] += 1
 
     def counts(self) -> dict[str, int]:
-        by_rank = [0] * len(_RANK_NAME)
-        for c in self.cells:
-            by_rank[c] += 1
-        return {name: by_rank[i] for i, name in enumerate(_RANK_NAME)}
+        return dict(zip(_RANK_NAME, self.tally))
 
     def ratio(self) -> float:
         """Fraction of coordinates with ANY decision (served or refused)
@@ -171,7 +177,7 @@ class CoverageMap:
         total = len(self.cells)
         if not total:
             return 0.0
-        return sum(1 for c in self.cells if c) / total
+        return (total - self.tally[0]) / total
 
     def payload(self) -> dict:
         n = 2 * self.k

@@ -13,6 +13,7 @@ rpc/ serving stack (whose import chain needs `cryptography`).
 from __future__ import annotations
 
 import json
+import random
 import re
 import urllib.request
 
@@ -308,6 +309,96 @@ class TestCoverageMap:
             for plane in ("jsonrpc", "rest", "grpc")
         }
         assert bodies["jsonrpc"] == bodies["rest"] == bodies["grpc"]
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    @pytest.mark.parametrize("k", [1, 2, 32, 64])
+    def test_kept_counts_match_a_full_recount(self, k, seed):
+        """Random tick sequences over all four states, repeats, attempted
+        downgrades and out-of-range coordinates: the kept tally answers
+        exactly what a recount of the grid (and of an independent model
+        of it) answers, down to the rendered /das/coverage bytes."""
+        rng = random.Random(seed * 1000 + k)
+        n = 2 * k
+        height = 100 + k
+        model: dict[tuple[int, int], int] = {}
+        for _ in range(200):
+            state = rng.choice(serve_api.COVERAGE_STATES)
+            coords = [
+                (rng.randint(-2, n + 1), rng.randint(-2, n + 1))
+                for _ in range(rng.randint(0, 6))
+            ]
+            # Re-tick a cell already decided, stronger or weaker.
+            if model and rng.random() < 0.5:
+                coords.append(rng.choice(sorted(model)))
+            serve_api.coverage_tick(height, k, coords, state)
+            rank = serve_api._STATE_RANK[state]
+            for row, col in coords:
+                if 0 <= row < n and 0 <= col < n:
+                    model[(row, col)] = max(model.get((row, col), 0), rank)
+
+        cov = serve_api._COVERAGE[height]
+        grid = bytearray(n * n)
+        for (row, col), rank in model.items():
+            grid[row * n + col] = rank
+        assert cov.cells == grid
+        recount = {name: 0 for name in serve_api._RANK_NAME}
+        for c in grid:
+            recount[serve_api._RANK_NAME[c]] += 1
+        ratio = sum(1 for c in grid if c) / len(grid)
+        assert cov.counts() == recount
+        assert cov.ratio() == ratio
+
+        expected = {
+            "height": height,
+            "square_size": k,
+            "ratio": ratio,
+            "counts": recount,
+        }
+        if n <= serve_api.MAX_COVERAGE_MAP_EDGE:
+            expected["map"] = [
+                "".join(serve_api._RANK_CHAR[c] for c in grid[r * n:(r + 1) * n])
+                for r in range(n)
+            ]
+            expected["map_omitted"] = False
+        else:
+            expected["map_omitted"] = True
+        assert cov.payload() == expected
+        status, _, body = serve_api.coverage_response({"height": str(height)})
+        assert (status, body) == (200, serve_api.render(expected))
+        assert serve_api.coverage_snapshot()[str(height)] == {
+            "square_size": k, "ratio": ratio, "counts": recount,
+        }
+        from celestia_app_tpu.trace.metrics import registry
+
+        gauge = registry().get("celestia_das_coverage_ratio")
+        values = {tuple(sorted(lbl.items())): v for lbl, v in gauge.samples()}
+        assert values[(("k", str(k)),)] == ratio
+
+    def test_tick_ratio_and_counts_never_scan_the_map(self):
+        class NoScan(bytearray):
+            def _scan(self, *args, **kwargs):
+                raise AssertionError("the coverage map was scanned")
+
+            __iter__ = __contains__ = __bytes__ = _scan
+            count = find = rfind = index = rindex = decode = _scan
+
+            def __getitem__(self, key):
+                if isinstance(key, slice):
+                    self._scan()
+                return super().__getitem__(key)
+
+        cov = serve_api.CoverageMap(height=1, k=64)
+        cov.tick([(0, 0), (5, 7)], "sampled")
+        cov.cells = NoScan(cov.cells)
+        cov.tick([(0, 0), (1, 1), (127, 127), (128, 0)], "verified")
+        cov.tick([(1, 1)], "sampled")
+        cov.tick([(2, 3)], "withheld")
+        cov.tick([(2, 3), (4, 4)], "tampered")
+        assert cov.counts() == {
+            "unseen": 128 * 128 - 6, "sampled": 1, "verified": 3,
+            "withheld": 0, "tampered": 2,
+        }
+        assert cov.ratio() == 6 / (128 * 128)
 
 
 def _get(url, headers=None):
