@@ -407,43 +407,6 @@ class App:
                     continue
         return normal + blob
 
-    def speculate_proposal(
-        self, data: BlockData, height: int | None = None,
-        round_: int | None = None,
-    ) -> bool:
-        """Enqueue the proposed square's extension SPECULATIVELY (the PR 9
-        seam's consensus call site, $CELESTIA_PIPE_SPECULATE): called by
-        the round-machine driver the moment a proposal's payload is known
-        to be the proposer's signed content, so the device dispatch is in
-        flight while the prevote window's host work runs — the LastCommit
-        signature batch, ante validation, blob-commitment checks — and
-        process_proposal's root derivation claims the finished result
-        instead of dispatching cold.  Best-effort by contract: any
-        mismatch (a round change re-proposed different bytes) discards
-        the claim and compute() runs normally; never raises into the
-        consensus path."""
-        import numpy as np
-
-        from celestia_app_tpu.constants import SHARE_SIZE
-        from celestia_app_tpu.da.eds import speculation_enabled, speculator
-
-        if not speculation_enabled():
-            return False
-        try:
-            sq = square.construct(
-                list(data.txs), self.max_effective_square_size()
-            )
-            if sq.is_empty() or sq.size != data.square_size:
-                return False
-            shares = sq.share_bytes()
-            k = sq.size
-            ods = np.frombuffer(
-                b"".join(shares), dtype=np.uint8
-            ).reshape(k, k, SHARE_SIZE)
-            return speculator().speculate(ods, height=height, round_=round_)
-        except Exception:  # chaos-ok: speculation is best-effort by contract
-            return False
-
     # --- ProcessProposal (app/process_proposal.go:24-158) -------------------
     def process_proposal(self, data: BlockData) -> bool:
         from celestia_app_tpu.trace.metrics import registry

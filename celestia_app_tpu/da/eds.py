@@ -300,186 +300,6 @@ def jit_pipeline_batched(k: int, batch: int, construction: str | None = None):
     )
 
 
-# --- speculative extend ------------------------------------------------------
-#
-# $CELESTIA_PIPE_SPECULATE=on arms cross-height speculation: a caller that
-# can SEE the next proposal early (a proposer assembling height h+1 while
-# height h is still gathering precommits) starts its extend+DAH dispatch
-# ahead of adoption and the eventual compute() claims the in-flight result
-# instead of dispatching again.  Correctness-free by construction: a claim
-# only hits when the claimed ODS bytes (and RS construction) are EXACTLY
-# what was speculated — a round change that re-proposes different content
-# digests differently and the entry is discarded, costing one wasted
-# dispatch and nothing else.  Every lowering is bit-identical (the chaos
-# ladder's standing proof), so even a ladder step between speculate and
-# claim cannot change a byte.
-
-
-def speculation_enabled() -> bool:
-    """$CELESTIA_PIPE_SPECULATE: "on"/"1" arms the speculative-extend
-    seam (default off — speculation trades wasted dispatches for
-    latency, a choice the operator makes)."""
-    import os
-
-    return os.environ.get("CELESTIA_PIPE_SPECULATE", "").lower() in (
-        "on", "1", "true",
-    )
-
-
-def _speculation_counter():
-    from celestia_app_tpu.trace.metrics import registry
-
-    return registry().counter(
-        "celestia_speculation_total",
-        "speculative extends by outcome: hit (claimed) / discard "
-        "(content or construction changed before adoption, e.g. a round "
-        "change re-proposed the square)",
-    )
-
-
-class SpeculativeExtender:
-    """One in-flight speculative extend (the next proposal's square).
-
-    `speculate()` digests the candidate ODS, dispatches the owned-input
-    pipeline asynchronously (JAX dispatch is an async enqueue — this
-    returns as soon as the program is queued), and parks the device
-    handles.  `claim()` returns the finished ExtendedDataSquare iff the
-    claimed bytes match the speculated digest; any mismatch — a round
-    change, a construction flip — discards the entry and the caller
-    computes normally.  `discard()` is the explicit round-change hook.
-
-    Holds at most ONE entry: speculation is about the block after the one
-    in consensus, and a second speculate() before the first resolves
-    replaces (and counts as discarding) the stale one.
-    """
-
-    def __init__(self):
-        import threading
-
-        self._lock = threading.Lock()
-        self._entry: dict | None = None
-
-    @staticmethod
-    def _digest(ods: np.ndarray) -> bytes:
-        import hashlib
-
-        return hashlib.sha256(np.ascontiguousarray(ods).tobytes()).digest()
-
-    def speculate(
-        self,
-        ods: np.ndarray,
-        *,
-        height: int | None = None,
-        round_: int | None = None,
-        construction: str | None = None,
-    ) -> bool:
-        """Start extending `ods` ahead of adoption; False when the seam
-        is off (callers need no second gate).  Rides guarded_dispatch so
-        a speculative fault walks the same retry/ladder path a real
-        dispatch would — and can never raise into the consensus loop that
-        merely HOPED to save latency."""
-        if not speculation_enabled():
-            return False
-        from celestia_app_tpu.chaos.degrade import guarded_dispatch
-
-        k = ods.shape[0]
-        construction = construction or active_construction()
-        digest = self._digest(ods)
-        try:
-            from celestia_app_tpu.kernels.fused import pipeline_mode_for_k
-
-            if pipeline_mode_for_k(k) in ("panel", "sharded_panel"):
-                # Same panel-granular staging as compute(): the runner
-                # uploads one row panel (or one mesh-wide panel step) at
-                # a time out of the host copy.
-                x = np.ascontiguousarray(ods, dtype=np.uint8)
-            else:
-                x = jnp.asarray(ods, dtype=jnp.uint8)
-            mode, out = guarded_dispatch(
-                lambda m: _pipeline_for_mode(m, k, construction, owned=True),
-                x,
-                refresh=lambda: jnp.asarray(ods, dtype=jnp.uint8),
-                k=k,
-            )
-        except Exception:  # chaos-ok: speculation is best-effort by contract
-            return False
-        with self._lock:
-            if self._entry is not None:
-                _speculation_counter().inc(outcome="discard")
-            self._entry = {
-                "digest": digest, "height": height, "round": round_,
-                "k": k, "construction": construction, "mode": mode,
-                "outputs": out,
-            }
-        return True
-
-    def claim(
-        self, ods: np.ndarray, construction: str | None = None
-    ) -> tuple["ExtendedDataSquare", str] | None:
-        """(eds, mode) when the in-flight speculation is EXACTLY the
-        square being adopted (bytes + construction), else None — with the
-        mismatched entry discarded (the round-change outcome)."""
-        with self._lock:
-            entry, self._entry = self._entry, None
-        if entry is None:
-            return None
-        construction = construction or active_construction()
-        if (
-            entry["k"] != ods.shape[0]
-            or entry["construction"] != construction
-            or entry["digest"] != self._digest(ods)
-        ):
-            _speculation_counter().inc(outcome="discard")
-            return None
-        _speculation_counter().inc(outcome="hit")
-        eds, rr, cr, droot = entry["outputs"]
-        return (
-            ExtendedDataSquare(eds, rr, cr, droot, entry["k"]),
-            entry["mode"],
-        )
-
-    def discard(self) -> bool:
-        """Drop the in-flight entry (the explicit round-change signal);
-        True when there was one."""
-        with self._lock:
-            entry, self._entry = self._entry, None
-        if entry is None:
-            return False
-        _speculation_counter().inc(outcome="discard")
-        return True
-
-    def pending(self) -> bool:
-        with self._lock:
-            return self._entry is not None
-
-
-_SPECULATOR = SpeculativeExtender()
-
-
-def _speculator_owned_bytes() -> int:
-    """Device bytes parked by the in-flight speculation (the outputs
-    claim() would adopt) — the ownership-ledger callback; 0 when no
-    speculation is pending."""
-    with _SPECULATOR._lock:
-        entry = _SPECULATOR._entry
-    if entry is None:
-        return 0
-    return sum(
-        int(getattr(arr, "nbytes", 0) or 0) for arr in entry["outputs"]
-    )
-
-
-from celestia_app_tpu.trace.device_ledger import register_owner as _register_owner  # noqa: E402
-
-_register_owner("speculative_extend", _speculator_owned_bytes)
-
-
-def speculator() -> SpeculativeExtender:
-    """The process-wide speculative extender (one in-flight next-block
-    speculation per process, like the consensus loop it serves)."""
-    return _SPECULATOR
-
-
 def warmup_sizes(upto: int) -> list[int]:
     """The upto=N expansion: every power of two 1..upto (pure, so the
     contract is testable without paying the compiles)."""
@@ -827,28 +647,6 @@ class ExtendedDataSquare:
         if k & (k - 1) or not 1 <= k <= MAX_CODEC_SQUARE_SIZE:
             raise ValueError(f"invalid square size {k}")
         assert ods.shape == (k, k, SHARE_SIZE), ods.shape
-        spec_outcome = None
-        if speculation_enabled() and _SPECULATOR.pending():
-            claimed = _SPECULATOR.claim(np.asarray(ods), construction)
-            if claimed is not None:
-                # The dispatch already ran at speculate() time; this call
-                # pays a content digest and nothing else.  compile="hit"
-                # by construction (speculate built the program).
-                eds_obj, spec_mode = claimed
-                journal.record(
-                    "compute", k, mode=spec_mode, compile="hit",
-                    speculation="hit",
-                )
-                _maybe_parity_check(
-                    np.asarray(ods), k,
-                    construction or active_construction(),
-                    eds_obj._data_root,
-                )
-                return eds_obj
-            # A pending entry that did not match IS the round-change
-            # outcome: the square was re-proposed with different bytes
-            # and the wasted dispatch is discarded, never served.
-            spec_outcome = "discard"
         sentinel_input = None  # a buffer still valid AFTER the dispatch
         if isinstance(ods, jax.Array):
             # jnp.asarray is a no-copy pass-through for a device array, so
@@ -865,7 +663,6 @@ class ExtendedDataSquare:
                 "compute", k, mode=mode, compile=state,
                 dispatch_ms=disp.get("duration_ms"),
                 **_panel_fields(mode, k),
-                **({"speculation": spec_outcome} if spec_outcome else {}),
             )
             sentinel_input = ods  # undonated: still live and immutable
         else:
@@ -904,7 +701,6 @@ class ExtendedDataSquare:
                 upload_ms=upload.get("duration_ms"),
                 dispatch_ms=disp.get("duration_ms"),
                 **_panel_fields(mode, k),
-                **({"speculation": spec_outcome} if spec_outcome else {}),
             )
             sentinel_input = ods  # the host copy (x may be donated away)
         _maybe_parity_check(

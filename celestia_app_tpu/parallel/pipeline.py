@@ -19,7 +19,7 @@ Three overlaps compose here:
     of first waiting out block i's dispatch call, so a dispatch
     round-trip no longer sits between two transfers.
 
-Cross-height continuous batching (this file's third era) adds three legs:
+Cross-height continuous batching (this file's third era) adds two legs:
 
   * persistent donated buffers: a small ring of staging buffers
     (`_BufferRing`, depth+1 slots) is allocated ONCE and recycled across
@@ -39,12 +39,6 @@ Cross-height continuous batching (this file's third era) adds three legs:
     latencies.  A batched-dispatch fault degrades to per-square dispatch
     through the normal guarded ladder (batched -> unbatched fused ->
     staged -> host), ticking celestia_recoveries_total{outcome=unbatched}.
-  * speculative extend lives in da/eds.SpeculativeExtender
-    ($CELESTIA_PIPE_SPECULATE): the consensus loop can start extending
-    the NEXT proposal while the current height is still voting, and
-    compute() claims the in-flight result on a content match (discarding
-    on round change — every lowering is bit-identical, so speculation is
-    a pure latency trade).
 
 Every drained block writes one `block_journal` row (trace/journal.py):
 upload/dispatch/drain ms plus the two queue stalls (uploader blocked on

@@ -560,17 +560,6 @@ class ConsensusDriver:
             return None
         if time_ns <= node.app.last_block_time_ns:
             return False  # block time must advance (BFT time monotonicity)
-        # The prevote window's speculative extend (the PR 9 seam's round-
-        # machine call site, $CELESTIA_PIPE_SPECULATE): the payload is the
-        # proposer's signed content, so enqueue the square's extension NOW
-        # — the device dispatch runs across the LastCommit signature batch
-        # and ante validation below, and process_proposal's root check
-        # claims the finished result.  A round change re-proposing
-        # different bytes makes the next compute() DISCARD the claim
-        # (celestia_speculation_total{outcome="discard"}; drilled by
-        # tests and scripts/chaos_soak.py's speculation drill).
-        node.app.speculate_proposal(data, height=prop.height,
-                                    round_=prop.round)
         # LastCommit: required after height 1; must attest the block id
         # this node itself committed at H-1 (its own stored record — NOT a
         # driver-local cache, which goes stale when heights apply via

@@ -439,7 +439,7 @@ def _applied_seats() -> dict:
         "CELESTIA_RS_XOR", "CELESTIA_SHA_PALLAS", "CELESTIA_SHA_FUSED",
         "CELESTIA_PIPE_FUSED", "CELESTIA_PIPE_PANEL",
         "CELESTIA_EXTEND_SHARDS", "CELESTIA_SERVE_SHARDS",
-        "CELESTIA_MEMPOOL_SHARDS", "CELESTIA_SPECULATE",
+        "CELESTIA_MEMPOOL_SHARDS",
     ):
         val = os.environ.get(var)
         if val is not None:

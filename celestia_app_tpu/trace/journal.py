@@ -7,8 +7,7 @@ crosses `da/eds` (fused or staged), `parallel/pipeline.BlockPipeline`
 the stage timings its path measured (upload ms, dispatch ms, queue-stall
 ms, drain latency), and the continuous-batching facts: `batch_size`
 (squares coalesced into the row's dispatch; 1 = unbatched) on stream
-rows, the `speculation` outcome (hit / discard) on compute rows when
-$CELESTIA_PIPE_SPECULATE is armed, and `panels` (row panels the square
+rows, and `panels` (row panels the square
 streamed through) on panel-mode rows ($CELESTIA_PIPE_PANEL) — read next
 to the per-dispatch `celestia_hbm_peak_bytes{point,k,source}` refresh
 below, the pair is the giant-square memory story per dispatch.  The batch-size distribution itself

@@ -22,12 +22,6 @@ LATENCY but never CORRECTNESS.  Four drills, one process:
                        pure-host fallback with proof bytes BIT-IDENTICAL
                        to the chaos-off batched run, all verifying
                        against the committed DAH data root.
-  2c. speculation drill — speculative extends ($CELESTIA_PIPE_SPECULATE)
-                       under injected dispatch faults and forced round
-                       changes (the adopted square differs from the
-                       speculated one): every mismatched claim must
-                       DISCARD and recompute, with committed roots
-                       bit-identical to the speculation-off run.
   2d. batched-fault drill — a persistent fault in the vmapped
                        multi-square dispatch ($CELESTIA_PIPE_BATCH) must
                        fall down the ladder (batched -> unbatched fused
@@ -750,81 +744,6 @@ def run_extend_shard_drill(k: int = 8, shards: int = 8,
                 os.environ.pop(key, None)
             else:
                 os.environ[key] = val
-
-
-def run_speculation_drill(k: int = 4, blocks: int = 6,
-                          spec: str = "seed=3,dispatch_fail=0.3") -> dict:
-    """The speculative-extend leg of the 'latency, never correctness'
-    claim: with $CELESTIA_PIPE_SPECULATE=on, every block speculates the
-    NEXT block's square ahead of adoption, and every other adoption is a
-    ROUND CHANGE (the adopted square differs from the speculated one, so
-    the claim must discard and recompute) — all under injected dispatch
-    faults so a speculative dispatch also walks the retry/ladder path.
-    Every committed root must be bit-identical to the speculation-off
-    chaos-off run, and the discards must actually have fired."""
-    import os
-
-    from celestia_app_tpu import chaos
-    from celestia_app_tpu.chaos import degrade
-    from celestia_app_tpu.da.eds import ExtendedDataSquare, speculator
-    from celestia_app_tpu.trace.metrics import registry
-
-    pairs = _deterministic_blocks(2 * blocks, k, seed=777)
-    adopted = [ods for _tag, ods in pairs[:blocks]]
-    reproposed = [ods for _tag, ods in pairs[blocks:]]
-
-    chaos.install("")  # speculation-off, chaos-off baseline
-    degrade.reset_for_tests()
-    saved = os.environ.get("CELESTIA_PIPE_SPECULATE")
-    os.environ.pop("CELESTIA_PIPE_SPECULATE", None)
-    baseline = [ExtendedDataSquare.compute(o).data_root() for o in adopted]
-
-    def _outcomes() -> dict:
-        out = {"hit": 0.0, "discard": 0.0}
-        for labels, val in registry().counter(
-            "celestia_speculation_total", ""
-        ).samples():
-            out[labels.get("outcome", "?")] = val
-        return out
-
-    before = _outcomes()
-    os.environ["CELESTIA_PIPE_SPECULATE"] = "on"
-    chaos.install(spec)
-    t0_ns = time.time_ns()
-    try:
-        roots = []
-        sp = speculator()
-        for i, ods in enumerate(adopted):
-            if i % 2:
-                # Round change: what was speculated is NOT what adoption
-                # brings — the claim must discard and compute fresh.
-                sp.speculate(reproposed[i], height=i, round_=0)
-            else:
-                sp.speculate(ods, height=i, round_=0)
-            roots.append(ExtendedDataSquare.compute(ods).data_root())
-    finally:
-        chaos.uninstall()
-        degrade.reset_for_tests()
-        if saved is None:
-            os.environ.pop("CELESTIA_PIPE_SPECULATE", None)
-        else:
-            os.environ["CELESTIA_PIPE_SPECULATE"] = saved
-    after = _outcomes()
-    hits = after["hit"] - before["hit"]
-    discards = after["discard"] - before["discard"]
-    identical = roots == baseline
-    return {
-        "blocks": blocks,
-        "k": k,
-        "roots_identical": identical,
-        "hits": hits,
-        "discards": discards,
-        # Hits are best-effort under dispatch_fail (a failed speculative
-        # dispatch simply never parks an entry); discards are the drill's
-        # point and MUST have fired on every round change that resolved.
-        "ok": identical and discards >= 1,
-        "detection": _detection(t0_ns),
-    }
 
 
 #: DAS sample counts the withholding drill sweeps (the detection-
@@ -1982,14 +1901,6 @@ def main(argv=None) -> int:
     if not esd["ok"]:
         failures.append(f"extend-shard drill failed: {esd}")
 
-    spc = run_speculation_drill(k=min(args.k, 8),
-                                blocks=min(args.blocks, 6))
-    print(f"speculation drill: {spc['blocks']} blocks @ k={spc['k']} -> "
-          f"roots_identical={spc['roots_identical']} hits={spc['hits']:.0f} "
-          f"discards={spc['discards']:.0f}", flush=True)
-    if not spc["ok"]:
-        failures.append(f"speculation drill failed: {spc}")
-
     bat = run_batched_fault_drill(k=min(args.k, 8),
                                   blocks=min(args.blocks, 6))
     print(f"batched-fault drill: {bat['blocks']} blocks @ k={bat['k']} "
@@ -2113,7 +2024,6 @@ def main(argv=None) -> int:
         ("WAL tear", wal.get("detection")),
         ("sampling", smp.get("detection")),  # healed by host fallback
         ("extend shard", esd.get("detection")),  # healed by the ladder
-        ("speculation", spc.get("detection")),  # discards heal silently
         ("batched fault", bat.get("detection")),
         ("withholding", wd.get("detection_signal")),
         ("adversary", adv.get("detection")),

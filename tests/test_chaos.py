@@ -281,16 +281,6 @@ class TestChaosSmoke:
         assert result["ok"], result
         assert _injections("gossip.send") > before
 
-    def test_speculation_drill_discards_on_round_change(self):
-        """Speculative extends under injected dispatch faults + forced
-        round changes: roots bit-identical to the speculation-off run,
-        with the mismatched claims actually discarded."""
-        soak = _load_soak()
-        result = soak.run_speculation_drill(k=2, blocks=4)
-        assert result["ok"], result
-        assert result["discards"] >= 1
-        assert result["roots_identical"]
-
     def test_batched_fault_drill_falls_down_the_ladder(self):
         """A persistent batched-dispatch fault: every root still
         bit-identical, the unbatched fallback fired, and the ladder

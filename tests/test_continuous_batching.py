@@ -1,6 +1,6 @@
-"""Cross-height continuous batching: batched + speculative paths are
-bit-identical to the unbatched fused pipeline, the persistent buffer ring
-never aliases a retained square, and the batched jit cache keys per
+"""Cross-height continuous batching: the batched path is bit-identical
+to the unbatched fused pipeline, the persistent buffer ring never
+aliases a retained square, and the batched jit cache keys per
 (k, batch, mode).
 
 Crypto-free (no TestNode import) so the whole module runs in this image.
@@ -17,11 +17,8 @@ import jax.numpy as jnp
 from celestia_app_tpu.constants import NAMESPACE_SIZE, SHARE_SIZE
 from celestia_app_tpu.da.eds import (
     ExtendedDataSquare,
-    SpeculativeExtender,
     _batched_pipeline_for_mode,
     jit_pipeline_batched,
-    speculation_enabled,
-    speculator,
 )
 from celestia_app_tpu.kernels.fused import (
     batched_is_built,
@@ -371,150 +368,6 @@ class TestBufferRing:
         ids_after = {id(h) for h in pipe._ring._hosts}
         assert ids_after == ids_before  # nothing was swapped or realloc'd
         assert pipe._ring.swaps == 0
-
-
-class TestSpeculativeExtend:
-    """$CELESTIA_PIPE_SPECULATE: claim on exact content, discard on any
-    divergence, bytes identical either way."""
-
-    def test_disabled_by_default(self, monkeypatch):
-        monkeypatch.delenv("CELESTIA_PIPE_SPECULATE", raising=False)
-        assert not speculation_enabled()
-        assert not SpeculativeExtender().speculate(random_ods(2, 1))
-
-    def test_hit_returns_identical_square(self, monkeypatch):
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        sp = SpeculativeExtender()
-        ods = random_ods(2, seed=11)
-        ref = ExtendedDataSquare.compute(ods.copy())
-        assert sp.speculate(ods, height=9, round_=0)
-        assert sp.pending()
-        claimed = sp.claim(ods)
-        assert claimed is not None
-        eds, mode = claimed
-        assert eds.data_root() == ref.data_root()
-        assert eds.row_roots() == ref.row_roots()
-        assert eds.col_roots() == ref.col_roots()
-        np.testing.assert_array_equal(eds.squared(), ref.squared())
-        assert not sp.pending()
-
-    def test_round_change_discards_and_recompute_is_identical(
-        self, monkeypatch
-    ):
-        """The correctness-free contract: a re-proposed square never
-        claims the stale speculation, and the fresh compute is
-        bit-identical to a never-speculated run."""
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        a, b = random_ods(2, seed=21), random_ods(2, seed=22)
-        ref_b = ExtendedDataSquare.compute(b.copy()).data_root()
-        sp = speculator()
-        sp.discard()  # isolate from any earlier test's entry
-        assert sp.speculate(a, height=3, round_=0)
-        got = ExtendedDataSquare.compute(b)  # round change: b adopted
-        assert got.data_root() == ref_b
-        assert not sp.pending()  # the stale entry was discarded
-
-    def test_construction_mismatch_discards(self, monkeypatch):
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        sp = SpeculativeExtender()
-        ods = random_ods(2, seed=31)
-        assert sp.speculate(ods, construction="vandermonde")
-        assert sp.claim(ods, construction="leopard") is None
-        assert not sp.pending()
-
-    def test_compute_journals_speculation_outcome(self, monkeypatch):
-        from celestia_app_tpu.trace import journal, traced
-
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        monkeypatch.setenv("CELESTIA_TRACE", "on")
-        sp = speculator()
-        sp.discard()
-        ods = random_ods(2, seed=41)
-        sp.speculate(ods, height=1, round_=0)
-        before = len(traced().table(journal.TABLE))
-        ExtendedDataSquare.compute(ods)
-        rows = traced().table(journal.TABLE)[before:]
-        assert any(r.get("speculation") == "hit" for r in rows)
-        # and the discard outcome on a round change
-        sp.speculate(ods, height=2, round_=0)
-        other = random_ods(2, seed=42)
-        before = len(traced().table(journal.TABLE))
-        ExtendedDataSquare.compute(other)
-        rows = traced().table(journal.TABLE)[before:]
-        assert any(r.get("speculation") == "discard" for r in rows)
-
-    def _assert_speculative_identical(self, k, construction, monkeypatch):
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        ods = random_ods(k, seed=500 + k)
-        sp = speculator()
-        sp.discard()  # nothing pending: this compute is the plain path
-        ref = ExtendedDataSquare.compute(ods.copy(), construction)
-        assert sp.speculate(ods, construction=construction)
-        got = sp.claim(ods, construction=construction)
-        assert got is not None, (k, construction)
-        eds, _mode = got
-        assert eds.data_root() == ref.data_root(), (k, construction)
-        assert eds.row_roots() == ref.row_roots()
-        assert eds.col_roots() == ref.col_roots()
-        np.testing.assert_array_equal(eds.squared(), ref.squared())
-
-    # Same fast/slow split as the batched matrix above.
-    @pytest.mark.parametrize("k,construction", [
-        (2, "vandermonde"), (2, "leopard"), (8, "vandermonde"),
-    ])
-    def test_speculative_path_bit_identical(self, k, construction,
-                                            monkeypatch):
-        """The claimed square equals a never-speculated compute byte for
-        byte — roots, data root, EDS — under both RS constructions."""
-        self._assert_speculative_identical(k, construction, monkeypatch)
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("k,construction", [
-        (8, "leopard"), (32, "vandermonde"), (32, "leopard"),
-    ])
-    def test_speculative_path_bit_identical_slow(self, k, construction,
-                                                 monkeypatch):
-        self._assert_speculative_identical(k, construction, monkeypatch)
-
-    def test_golden_vector_through_speculative_claim(self, monkeypatch):
-        """The reference golden DAH hash via a claimed speculation."""
-        from celestia_app_tpu.da.dah import DataAvailabilityHeader
-
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        k = 2
-        shares = [_golden_share()] * (k * k)
-        ods = np.frombuffer(b"".join(shares), dtype=np.uint8).reshape(
-            k, k, SHARE_SIZE
-        )
-        sp = speculator()
-        sp.discard()
-        assert sp.speculate(ods.copy(), height=1, round_=0)
-        eds = ExtendedDataSquare.compute(ods)  # claims the speculation
-        dah = DataAvailabilityHeader(
-            row_roots=eds.row_roots(), column_roots=eds.col_roots()
-        )
-        assert dah.hash() == K2_HASH
-
-    def test_explicit_discard_counts(self, monkeypatch):
-        from celestia_app_tpu.trace.metrics import registry
-
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-
-        def outcomes():
-            vals = {"hit": 0.0, "discard": 0.0}
-            for labels, v in registry().counter(
-                "celestia_speculation_total", ""
-            ).samples():
-                vals[labels["outcome"]] = v
-            return vals
-
-        sp = SpeculativeExtender()
-        before = outcomes()
-        assert sp.speculate(random_ods(2, seed=51))
-        assert sp.discard()
-        assert not sp.discard()  # idempotent: nothing left to drop
-        after = outcomes()
-        assert after["discard"] == before["discard"] + 1
 
 
 class TestBatchedFaultFallback:

@@ -124,8 +124,9 @@ class TestFusedParity:
     def test_golden_vectors_through_fused(self):
         """The reference golden DAH hash via an explicitly-fused, donated
         dispatch (k=2; the k=128 reference size is the slow twin below —
-        its DONATED compile is ~40 s on this image and the default-path
-        k=128 golden stays pinned in tier-1 by test_golden_vectors.py)."""
+        its DONATED compile is ~40 s on a 1-core CPU; the default-path
+        k=128 golden is pinned in the fast tier by
+        test_golden_vectors.py::test_k128_dah_golden)."""
         self._golden_through_fused(2, K2_HASH)
 
     @pytest.mark.slow

@@ -13,8 +13,6 @@ pipeline breaks them.
 
 from __future__ import annotations
 
-import pytest
-
 from celestia_app_tpu import merkle
 from celestia_app_tpu.constants import NAMESPACE_SIZE, SHARE_SIZE
 from celestia_app_tpu.da.dah import (
@@ -65,10 +63,10 @@ def test_k2_dah_golden():
     assert dah.hash() == K2_HASH
 
 
-# ~50 s on the 1-core fallback image (a 256x256 EDS through the full
-# device pipeline); k=2 keeps the share/NMT/merkle vector chain pinned in
-# the fast tier, this leg pins the large-square path in the slow tier.
-@pytest.mark.slow
+# The reference's hard-cap square (a 256x256 EDS) through the default
+# ExtendedDataSquare.compute path every block takes: k=2 pins the
+# share/NMT/merkle vector chain, this leg pins the large-square program.
+# ~20 s on a multi-core CPU, ~50 s on a 1-core one; kept in the fast tier.
 def test_k128_dah_golden():
     dah = _golden_dah(128)
     assert len(dah.row_roots) == 256 and len(dah.column_roots) == 256

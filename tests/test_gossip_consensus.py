@@ -176,108 +176,6 @@ class TestGossipRounds:
                 s.stop()
 
 
-class TestPrevoteWindowSpeculation:
-    """ISSUE-10 satellite: speculator().speculate() wired into the
-    proposer's prevote window (rpc/gossip._validate_payload ->
-    app.speculate_proposal), drilled under a forced round change so a
-    discarded speculation is observed END-TO-END — from the driver seam
-    through compute()'s claim accounting."""
-
-    @staticmethod
-    def _outcomes() -> dict:
-        from celestia_app_tpu.trace.metrics import registry
-
-        out = {"hit": 0.0, "discard": 0.0}
-        for labels, val in registry().counter(
-            "celestia_speculation_total", ""
-        ).samples():
-            out[labels.get("outcome", "?")] = val
-        return out
-
-    @staticmethod
-    def _blob_tx(key, chain_id: str, seed: int, seq: int = 0) -> bytes:
-        """One signed BlobTx (the shape test_tx_blob pins)."""
-        from celestia_app_tpu.modules.blob.types import new_msg_pay_for_blobs
-        from celestia_app_tpu.shares.namespace import Namespace
-        from celestia_app_tpu.shares.sparse import Blob
-        from celestia_app_tpu.tx.envelopes import BlobTx
-        from celestia_app_tpu.tx.messages import Coin
-        from celestia_app_tpu.tx.sign import Fee, build_and_sign
-
-        blob = Blob(Namespace.v0(bytes([seed]) * 10), bytes([seed]) * 256, 0)
-        msg = new_msg_pay_for_blobs(key.public_key().address(), [blob])
-        fee = Fee((Coin("utia", 2000),), 200_000)
-        raw_tx = build_and_sign([msg], key, chain_id, 1, seq, fee)
-        return BlobTx(raw_tx, (blob,)).marshal()
-
-    def test_round_change_discards_speculation_end_to_end(self, monkeypatch):
-        """Speculate proposal A in the prevote window; the round changes
-        and proposal B (different txs) is what process_proposal validates
-        — the parked speculation must DISCARD, the verdict must stay
-        correct, and a re-speculated B must then HIT."""
-        from celestia_app_tpu.da.eds import speculator
-        from celestia_app_tpu.testutil.testnode import TestNode
-
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        node = TestNode()
-        app = node.app
-        speculator().discard()  # clean slate
-        data_a = app.prepare_proposal(
-            [self._blob_tx(node.keys[0], node.chain_id, seed=1)]
-        )
-        data_b = app.prepare_proposal(
-            [self._blob_tx(node.keys[1], node.chain_id, seed=2)]
-        )
-        assert data_a.hash != data_b.hash
-        assert data_a.txs and data_b.txs, "blob txs must survive prepare"
-
-        # Prevote window for round 0: proposal A's payload verified as
-        # the proposer's content -> the driver speculates it.
-        before = self._outcomes()
-        assert app.speculate_proposal(data_a, height=2, round_=0)
-        assert speculator().pending()
-        # FORCED ROUND CHANGE: round 1 re-proposes B; the validator's
-        # process_proposal extends B's square -> the A claim discards.
-        assert app.process_proposal(data_b)
-        after = self._outcomes()
-        assert after["discard"] - before["discard"] >= 1
-        assert not speculator().pending()
-
-        # And the happy path through the same seam: speculate B, process
-        # B -> the claim HITS (the extension ran once, in the window).
-        before = self._outcomes()
-        assert app.speculate_proposal(data_b, height=2, round_=1)
-        assert app.process_proposal(data_b)
-        after = self._outcomes()
-        assert after["hit"] - before["hit"] >= 1
-
-    def test_cluster_speculates_in_prevote_window(self, monkeypatch):
-        """Live wiring: a gossip cluster with $CELESTIA_PIPE_SPECULATE=on
-        must tick speculation outcomes (the _validate_payload call site)
-        while still committing identical app hashes."""
-        monkeypatch.setenv("CELESTIA_PIPE_SPECULATE", "on")
-        before = self._outcomes()
-        keys, nodes, servers = _gossip_cluster(3, 3)
-        try:
-            for n in nodes:
-                n.consensus_driver.start()
-            # Non-empty blocks so the speculated square is real work;
-            # submitted to a NON-proposer, reaching proposers by gossip.
-            nodes[1].broadcast(
-                self._blob_tx(keys[0], nodes[1].chain_id, seed=9)
-            )
-            _wait_height(nodes, 3)
-            h = min(n.app.height for n in nodes)
-            assert len({n.app.cms.app_hash_at(h) for n in nodes}) == 1
-        finally:
-            for s in servers:
-                s.stop()
-        after = self._outcomes()
-        assert (after["hit"] + after["discard"]) > (
-            before["hit"] + before["discard"]
-        ), "no prevote-window speculation was observed in the cluster"
-
-
 @pytest.mark.slow
 class TestDevnetGossip:
     def test_kill_proposer_devnet_recovers(self, tmp_path):
